@@ -10,9 +10,11 @@ Design (TPU-native, not a CUDA port):
 * BlockSpec tiles q/k/v into VMEM at MXU-aligned shapes (multiples of 128
   on the contraction dims).
 * masking is *position-based*: q/kv absolute positions ride in as tiny VMEM
-  blocks, so the same kernel serves causal, sliding-window, bidirectional
-  (encoder) and padded-cache attention; GQA is an index-map (kv head =
-  q head // group) — no head replication in HBM.
+  blocks (q positions as a ``[Bq, 1]`` column, kv positions as a ``[1, Bk]``
+  row, so the mask is a plain broadcast compare), so the same kernel serves
+  causal, sliding-window, bidirectional (encoder) and padded-cache
+  attention; GQA is an index-map (kv head = q head // group) — no head
+  replication in HBM.
 
 ``flash_attention`` (bottom) is the public wrapper: layout transposes,
 padding to block multiples, and the pallas_call.  The pure-jnp oracle is
@@ -49,8 +51,8 @@ def _flash_kernel(
     q = q_ref[0, 0].astype(jnp.float32)         # [Bq, Dk]
     k = k_ref[0, 0].astype(jnp.float32)         # [Bk, Dk]
     v = v_ref[0, 0].astype(jnp.float32)         # [Bk, Dv]
-    qp = qpos_ref[0].astype(jnp.int32)          # [Bq]
-    kp = kpos_ref[0].astype(jnp.int32)          # [Bk]
+    qp = qpos_ref[0]                            # [Bq, 1]
+    kp = kpos_ref[0]                            # [1, Bk]
 
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
@@ -58,11 +60,11 @@ def _flash_kernel(
     if softcap > 0:
         s = softcap * jnp.tanh(s / softcap)
 
-    mask = (kp < VALID_POS_LIMIT)[None, :]
+    mask = kp < VALID_POS_LIMIT
     if causal:
-        mask &= kp[None, :] <= qp[:, None]
+        mask &= kp <= qp
     if window is not None:
-        mask &= kp[None, :] > qp[:, None] - window
+        mask &= kp > qp - window
     s = jnp.where(mask, s, NEG_INF)
 
     m_prev = m_scr[...]
@@ -128,6 +130,8 @@ def flash_attention(
                      constant_values=2 ** 30)    # padding -> invalid
     nq = qt.shape[2] // bq
     nk = kt.shape[2] // bk
+    qp = qp[:, :, None]                          # [B, Sq, 1]
+    kp = kp[:, None, :]                          # [B, 1, Skv]
 
     kernel = functools.partial(
         _flash_kernel, scale=scale, causal=causal, window=sliding_window,
@@ -137,8 +141,8 @@ def flash_attention(
         kernel,
         grid=(b, hq, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq), lambda ib, ih, iq, ik: (ib, iq)),
-            pl.BlockSpec((1, bk), lambda ib, ih, iq, ik: (ib, ik)),
+            pl.BlockSpec((1, bq, 1), lambda ib, ih, iq, ik: (ib, iq, 0)),
+            pl.BlockSpec((1, 1, bk), lambda ib, ih, iq, ik: (ib, 0, ik)),
             pl.BlockSpec((1, 1, bq, dk), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, bk, dk),
                          lambda ib, ih, iq, ik, g=group: (ib, ih // g, ik, 0)),
@@ -148,13 +152,11 @@ def flash_attention(
         out_specs=pl.BlockSpec((1, 1, bq, dv), lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hq, qt.shape[2], dv), q.dtype),
         scratch_shapes=[
-            pl.MemorySpace.ANY if False else _vmem((bq,), jnp.float32),
+            _vmem((bq,), jnp.float32),
             _vmem((bq,), jnp.float32),
             _vmem((bq, dv), jnp.float32),
         ],
-        # lint: allow(host-sync): trace-time backend probe — picks the
-        # interpret path off-TPU; retracing on backend change is intended
-        interpret=interpret or (jax.default_backend() != "tpu"),
+        interpret=interpret,
     )(qp, kp, qt, kt, vt)
     out = jnp.moveaxis(out, 1, 2)
     if pad_q:

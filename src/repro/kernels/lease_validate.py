@@ -2,19 +2,23 @@
 
 When a replica (pod controller) validates a *batch* of remote/forwarded
 transactions (Lilac-TM §3.2: forwarded transactions are certified at the
-target without re-execution), the work is: gather each transaction's
-read-set versions from the store's version array, compare against the
+target without re-execution), the work is: look up each transaction's
+read-set versions in the store's version array, compare against the
 snapshot versions, and check write locks.  At pod scale (thousands of
 in-flight certifications per lease window) this is a bandwidth-bound
-gather+compare — exactly the kind of loop worth a VMEM-resident kernel.
+gather+compare.
 
-Tiling: transactions are tiled over the grid; the version array is tiled
-into VMEM *chunks* with the gather performed as ``chunk-local compare``
-(a one-hot-free masked equality over the chunk) — the TPU-native
-reformulation of a random gather: each (txn-tile × version-chunk) cell
-checks only the read entries whose item falls in the chunk, accumulating a
-per-transaction conflict flag across chunks (innermost grid dim, scratch
-persists).
+The TPU has no general vector gather, so the lookup is reformulated as a
+*chunk-local compare*: the read (and write) entries are flattened into one
+column ``[E, 1]`` and the version (lock) array is streamed through VMEM as
+lane-dense ``[1, chunk]`` rows.  Each (entry-tile × chunk) cell compares
+every entry's item against the chunk's lane ids; an entry is bad when the
+lane it hits holds a value other than the one it expects.  The chunk axis
+is the innermost grid dim, so the per-entry flag accumulates in the
+resident output block across the sweep.  Reads expect their snapshot
+version; writes run through the same kernel against the lock array
+expecting 0 (unlocked).  Per-transaction verdicts are the row-wise OR
+outside the kernel.
 """
 from __future__ import annotations
 
@@ -24,48 +28,58 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+LANES = 128
+SUBLANES = 8
 
-def _validate_kernel(
-    items_ref, vers_ref, witems_ref, store_ref, locks_ref,   # inputs
-    ok_ref,                                                   # output [Bt]
-    bad_scr,                                                  # scratch [Bt]
-    *, n_chunks: int, chunk: int,
-):
+
+def _mismatch_kernel(idx_ref, want_ref, vals_ref, bad_ref, *, chunk: int):
     ic = pl.program_id(1)
 
     @pl.when(ic == 0)
     def _init():
-        bad_scr[...] = jnp.zeros_like(bad_scr)
+        bad_ref[...] = jnp.zeros_like(bad_ref)
 
-    items = items_ref[...]            # [Bt, R] int32 (-1 padded)
-    vers = vers_ref[...]              # [Bt, R] int32
-    witems = witems_ref[...]          # [Bt, W] int32 (-1 padded)
-    store = store_ref[...]            # [chunk] int32
-    locks = locks_ref[...]            # [chunk] int32 (0/1)
+    idx = idx_ref[...]                                   # [te, 1] int32
+    lane = ic * chunk + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1)
+    hit = idx == lane                                    # [te, chunk]
+    wrong = hit & (vals_ref[...] != want_ref[...])       # [1,c] vs [te,1]
+    bad_ref[...] = jnp.maximum(
+        bad_ref[...],
+        jnp.max(wrong.astype(jnp.int32), axis=1, keepdims=True))
 
-    lo = ic * chunk
-    # read-set: entries whose item falls in this chunk must match versions
-    in_chunk = (items >= lo) & (items < lo + chunk)
-    local = jnp.clip(items - lo, 0, chunk - 1)
-    cur = jnp.take(store, local, axis=0)              # [Bt, R]
-    mismatch = in_chunk & (cur != vers)
-    # write-set: locked items are conflicts
-    w_in = (witems >= lo) & (witems < lo + chunk)
-    wlocal = jnp.clip(witems - lo, 0, chunk - 1)
-    wlocked = w_in & (jnp.take(locks, wlocal, axis=0) > 0)
-    bad_scr[...] = (
-        bad_scr[...]
-        + jnp.sum(mismatch.astype(jnp.int32), axis=1)
-        + jnp.sum(wlocked.astype(jnp.int32), axis=1)
-    )
 
-    @pl.when(ic == n_chunks - 1)
-    def _finish():
-        ok_ref[...] = (bad_scr[...] == 0)
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _mismatch(values, idx, want, *, block_entries: int, chunk: int,
+              interpret: bool):
+    """``bad[e] = values[idx[e]] != want[e]`` for ``idx[e] >= 0`` (else 0)."""
+    e = idx.shape[0]
+    n = values.shape[0]
+    chunk = min(_round_up(chunk, LANES), _round_up(n, LANES))
+    te = min(_round_up(block_entries, SUBLANES), _round_up(e, SUBLANES))
+    n_pad, e_pad = _round_up(n, chunk), _round_up(e, te)
+    values = jnp.pad(values, (0, n_pad - n)).reshape(1, n_pad)
+    idx = jnp.pad(idx, (0, e_pad - e), constant_values=-1).reshape(e_pad, 1)
+    want = jnp.pad(want, (0, e_pad - e)).reshape(e_pad, 1)
+    bad = pl.pallas_call(
+        functools.partial(_mismatch_kernel, chunk=chunk),
+        grid=(e_pad // te, n_pad // chunk),
+        in_specs=[
+            pl.BlockSpec((te, 1), lambda ie, ic: (ie, 0)),
+            pl.BlockSpec((te, 1), lambda ie, ic: (ie, 0)),
+            pl.BlockSpec((1, chunk), lambda ie, ic: (0, ic)),
+        ],
+        out_specs=pl.BlockSpec((te, 1), lambda ie, ic: (ie, 0)),
+        out_shape=jax.ShapeDtypeStruct((e_pad, 1), jnp.int32),
+        interpret=interpret,
+    )(idx, want, values)
+    return bad[:e, 0] > 0
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_txns", "chunk", "interpret"),
+    jax.jit, static_argnames=("block_entries", "chunk", "interpret"),
 )
 def lease_validate(
     store_versions: jax.Array,    # [n_items] int32
@@ -74,8 +88,8 @@ def lease_validate(
     write_locks: jax.Array,       # [n_items] int32 (0/1)
     write_items: jax.Array,       # [B, W] int32 (-1 padded)
     *,
-    block_txns: int = 256,
-    chunk: int = 4096,
+    block_entries: int = 256,
+    chunk: int = 1024,
     interpret: bool = False,
 ) -> jax.Array:
     # normalize dtypes at the boundary: callers hand numpy buffers of
@@ -87,44 +101,11 @@ def lease_validate(
     read_versions = jnp.asarray(read_versions, jnp.int32)
     write_locks = jnp.asarray(write_locks, jnp.int32)
     write_items = jnp.asarray(write_items, jnp.int32)
-    b, r = read_items.shape
-    n = store_versions.shape[0]
-    chunk = min(chunk, n)
-    pad_n = (-n) % chunk
-    if pad_n:
-        store_versions = jnp.pad(store_versions, (0, pad_n), constant_values=-2)
-        write_locks = jnp.pad(write_locks, (0, pad_n))
-    bt = min(block_txns, b)
-    pad_b = (-b) % bt
-    if pad_b:
-        read_items = jnp.pad(read_items, ((0, pad_b), (0, 0)), constant_values=-1)
-        read_versions = jnp.pad(read_versions, ((0, pad_b), (0, 0)))
-        write_items = jnp.pad(write_items, ((0, pad_b), (0, 0)), constant_values=-1)
-    nb = read_items.shape[0] // bt
-    nc = store_versions.shape[0] // chunk
-
-    kernel = functools.partial(_validate_kernel, n_chunks=nc, chunk=chunk)
-    ok = pl.pallas_call(
-        kernel,
-        grid=(nb, nc),
-        in_specs=[
-            pl.BlockSpec((bt, r), lambda ib, ic: (ib, 0)),
-            pl.BlockSpec((bt, r), lambda ib, ic: (ib, 0)),
-            pl.BlockSpec((bt, write_items.shape[1]), lambda ib, ic: (ib, 0)),
-            pl.BlockSpec((chunk,), lambda ib, ic: (ic,)),
-            pl.BlockSpec((chunk,), lambda ib, ic: (ic,)),
-        ],
-        out_specs=pl.BlockSpec((bt,), lambda ib, ic: (ib,)),
-        out_shape=jax.ShapeDtypeStruct((read_items.shape[0],), jnp.bool_),
-        scratch_shapes=[_vmem((bt,), jnp.int32)],
-        # lint: allow(host-sync): trace-time backend probe — picks the
-        # interpret path off-TPU; retracing on backend change is intended
-        interpret=interpret or (jax.default_backend() != "tpu"),
-    )(read_items, read_versions, write_items, store_versions, write_locks)
-    return ok[:b]
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
+    b = read_items.shape[0]
+    kw = dict(block_entries=block_entries, chunk=chunk, interpret=interpret)
+    stale = _mismatch(store_versions, read_items.reshape(-1),
+                      read_versions.reshape(-1), **kw)
+    locked = _mismatch(write_locks, write_items.reshape(-1),
+                       jnp.zeros(write_items.size, jnp.int32), **kw)
+    return ~(stale.reshape(b, -1).any(axis=1)
+             | locked.reshape(b, -1).any(axis=1))

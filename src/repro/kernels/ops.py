@@ -1,8 +1,9 @@
-"""Jit'd public wrappers over the Pallas kernels with automatic fallback.
+"""Jit'd public wrappers over the Pallas kernels: the one dispatch point.
 
-``backend="auto"`` uses the Pallas kernel on TPU and the pure-jnp oracle
-elsewhere (kernels still run under ``interpret=True`` in the test-suite
-shape sweeps).
+``backend="auto"`` uses the compiled Pallas kernel on TPU and the pure-jnp
+oracle elsewhere; ``backend="pallas"`` forces the kernel, compiled on TPU
+and interpreted on other backends (the kernels themselves take
+``interpret`` as given and never probe the backend).
 """
 from __future__ import annotations
 
@@ -11,26 +12,22 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from . import ref
+from . import kernel_mode, ref
 from .flash_attention import flash_attention as _flash
 from .lease_validate import lease_validate as _lease_validate
 from .ssd_scan import ssd_scan as _ssd
 
 
-def _use_pallas(backend: str) -> bool:
-    if backend == "auto":
-        return jax.default_backend() == "tpu"
-    return backend == "pallas"
-
-
 def attention(q, k, v, *, q_positions, kv_positions, causal=True,
               sliding_window=None, logit_softcap=0.0, scale=None,
               backend: str = "auto"):
-    if _use_pallas(backend):
+    interpret = kernel_mode(backend)
+    if interpret is not None:
         return _flash(q, k, v, q_positions=q_positions,
                       kv_positions=kv_positions, causal=causal,
                       sliding_window=sliding_window,
-                      logit_softcap=logit_softcap, scale=scale)
+                      logit_softcap=logit_softcap, scale=scale,
+                      interpret=interpret)
     return ref.sdpa_ref(q, k, v, q_positions=q_positions,
                         kv_positions=kv_positions, causal=causal,
                         sliding_window=sliding_window,
@@ -38,8 +35,10 @@ def attention(q, k, v, *, q_positions, kv_positions, causal=True,
 
 
 def ssd(x, dt, a, b_mat, c_mat, *, chunk=256, h0=None, backend: str = "auto"):
-    if _use_pallas(backend) and b_mat.shape[2] == 1:
-        return _ssd(x, dt, a, b_mat, c_mat, chunk=chunk, h0=h0)
+    interpret = kernel_mode(backend)
+    if interpret is not None and b_mat.shape[2] == 1:
+        return _ssd(x, dt, a, b_mat, c_mat, chunk=chunk, h0=h0,
+                    interpret=interpret)
     return ref.ssd_ref(x, dt, a, b_mat, c_mat, chunk=chunk, h0=h0)
 
 
@@ -109,8 +108,9 @@ def validate_transactions(
         write_locks = jnp.asarray(write_locks, jnp.int32)
     if write_items is None:
         write_items = jnp.full((b, 1), -1, jnp.int32)
-    if _use_pallas(backend):
+    interpret = kernel_mode(backend)
+    if interpret is not None:
         return _lease_validate(store_versions, read_items, read_versions,
-                               write_locks, write_items)
+                               write_locks, write_items, interpret=interpret)
     return _lease_validate_ref_jit(store_versions, read_items, read_versions,
                                    write_locks, write_items)

@@ -21,10 +21,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
+def _bdot(lhs, rhs, contract):
+    """Head-batched f32 matmul: batch dim 0, contracting ``contract``."""
+    return jax.lax.dot_general(
+        lhs, rhs, (contract, ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
 def _ssd_kernel(
-    x_ref, dt_ref, a_ref, b_ref, c_ref, h0_ref,     # inputs
-    y_ref, hout_ref,                                 # outputs
-    state_scr,                                       # VMEM scratch [Hb, P, N]
+    x_ref, dtr_ref, dtc_ref, a_ref, b_ref, c_ref, h0_ref,   # inputs
+    y_ref, hout_ref,                                         # outputs
+    state_scr,                                               # VMEM [Hb, P, N]
     *, nc: int,
 ):
     inc = pl.program_id(2)
@@ -33,35 +41,45 @@ def _ssd_kernel(
     def _init():
         state_scr[...] = h0_ref[0].astype(jnp.float32)
 
-    x = x_ref[0].astype(jnp.float32)          # [L, Hb, P]
-    dt = dt_ref[0].astype(jnp.float32)        # [L, Hb]
-    a = a_ref[...].astype(jnp.float32)        # [Hb]
-    bm = b_ref[0, :, 0, :].astype(jnp.float32)   # [L, N]   (G == 1)
-    cm = c_ref[0, :, 0, :].astype(jnp.float32)   # [L, N]
+    x = x_ref[0].astype(jnp.float32)          # [Hb, L, P]
+    dt_row = dtr_ref[0].astype(jnp.float32)   # [Hb, 1, L]
+    dt_col = dtc_ref[0].astype(jnp.float32)   # [Hb, L, 1]
+    a = a_ref[...].astype(jnp.float32)        # [Hb, 1, 1]
+    hb, l, _ = x.shape
+    n = b_ref.shape[-1]
+    bm = jnp.broadcast_to(b_ref[0].astype(jnp.float32)[None], (hb, l, n))
+    cm = jnp.broadcast_to(c_ref[0].astype(jnp.float32)[None], (hb, l, n))
 
-    l = x.shape[0]
-    da = dt * a[None, :]                      # [L, Hb] log-decay per step
-    dacum = jnp.cumsum(da, axis=0)            # [L, Hb]
+    # inclusive cumsum of the log-decay along L, in both layouts, as
+    # triangular matmuls (row i / column j of ``tri`` marks j <= i)
+    ii = jax.lax.broadcasted_iota(jnp.int32, (hb, l, l), 1)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (hb, l, l), 2)
+    tri = jj <= ii
+    trif = tri.astype(jnp.float32)
+    dacum_col = _bdot(trif, dt_col * a, ((2,), (1,)))        # [Hb, L, 1]
+    dacum_row = _bdot(dt_row * a, trif, ((2,), (2,)))        # [Hb, 1, L]
 
     # --- intra-chunk quadratic term -------------------------------------
-    # seg[h, i, j] = dacum[i,h] - dacum[j,h]  (i >= j)
-    seg = dacum.T[:, :, None] - dacum.T[:, None, :]          # [Hb, L, L]
-    tri = jnp.tril(jnp.ones((l, l), jnp.float32))
-    decay = jnp.exp(jnp.where(tri > 0, seg, -jnp.inf)) * tri  # [Hb, L, L]
-    cb = jax.lax.dot_general(                                 # [L, L]
-        cm, bm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-    )
-    w = cb[None, :, :] * decay * dt.T[:, None, :]             # [Hb, L(i), L(j)]
-    y_diag = jnp.einsum("hij,jhp->ihp", w, x)                 # [L, Hb, P]
+    # seg[h, i, j] = dacum[h, i] - dacum[h, j]  (i >= j)
+    seg = dacum_col - dacum_row                               # [Hb, L, L]
+    decay = jnp.where(tri, jnp.exp(jnp.where(tri, seg, 0.0)), 0.0)
+    cb = _bdot(cm, bm, ((2,), (2,)))                          # [Hb, L, L]
+    w = cb * decay * dt_row                                   # [Hb, L(i), L(j)]
+    y_diag = _bdot(w, x, ((2,), (1,)))                        # [Hb, L, P]
 
     # --- contribution of the carried state --------------------------------
     state = state_scr[...]                                     # [Hb, P, N]
-    y_off = jnp.einsum("ln,hpn,lh->lhp", cm, state, jnp.exp(dacum))
+    y_off = _bdot(cm, state, ((2,), (2,))) * jnp.exp(dacum_col)
 
     # --- state update -------------------------------------------------------
-    tail = jnp.exp(dacum[-1:, :] - dacum)                      # [L, Hb]
-    upd = jnp.einsum("ln,lh,lhp->hpn", bm, tail * dt, x)
-    state_scr[...] = state * jnp.exp(dacum[-1, :])[:, None, None] + upd
+    last = dacum_col[:, l - 1:, :]                             # [Hb, 1, 1]
+    xw = x * (jnp.exp(last - dacum_col) * dt_col)              # [Hb, L, P]
+    upd = _bdot(jnp.swapaxes(xw, 1, 2), bm, ((2,), (1,)))      # [Hb, P, N]
+    # the chunk's total decay as an [Hb, 1, N] row (a matmul against ones):
+    # Mosaic cannot broadcast an [Hb, 1, 1] scalar over sublanes and lanes
+    ones = jnp.ones((hb, l, n), jnp.float32)
+    keep = jnp.exp(_bdot(dt_row * a, ones, ((2,), (1,))))     # [Hb, 1, N]
+    state_scr[...] = state * keep + upd
 
     y_ref[0] = (y_diag + y_off).astype(y_ref.dtype)
 
@@ -101,32 +119,36 @@ def ssd_scan(
     if h0 is None:
         h0 = jnp.zeros((bsz, h, p, n), jnp.float32)
 
+    # head-major layouts keep every block's last two dims tile-aligned:
+    # x/y as [B, H, S, P], dt as a lane-dense row and a sublane column
+    xt = jnp.moveaxis(x, 2, 1)
+    dtt = jnp.moveaxis(dt, 2, 1)
     kernel = functools.partial(_ssd_kernel, nc=nc)
     y, hout = pl.pallas_call(
         kernel,
         grid=(bsz, nh, nc),
         in_specs=[
-            pl.BlockSpec((1, chunk, hb, p), lambda ib, ih, ic: (ib, ic, ih, 0)),
-            pl.BlockSpec((1, chunk, hb), lambda ib, ih, ic: (ib, ic, ih)),
-            pl.BlockSpec((hb,), lambda ib, ih, ic: (ih,)),
-            pl.BlockSpec((1, chunk, 1, n), lambda ib, ih, ic: (ib, ic, 0, 0)),
-            pl.BlockSpec((1, chunk, 1, n), lambda ib, ih, ic: (ib, ic, 0, 0)),
+            pl.BlockSpec((1, hb, chunk, p), lambda ib, ih, ic: (ib, ih, ic, 0)),
+            pl.BlockSpec((1, hb, 1, chunk), lambda ib, ih, ic: (ib, ih, 0, ic)),
+            pl.BlockSpec((1, hb, chunk, 1), lambda ib, ih, ic: (ib, ih, ic, 0)),
+            pl.BlockSpec((hb, 1, 1), lambda ib, ih, ic: (ih, 0, 0)),
+            pl.BlockSpec((1, chunk, n), lambda ib, ih, ic: (ib, ic, 0)),
+            pl.BlockSpec((1, chunk, n), lambda ib, ih, ic: (ib, ic, 0)),
             pl.BlockSpec((1, hb, p, n), lambda ib, ih, ic: (ib, ih, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, chunk, hb, p), lambda ib, ih, ic: (ib, ic, ih, 0)),
+            pl.BlockSpec((1, hb, chunk, p), lambda ib, ih, ic: (ib, ih, ic, 0)),
             pl.BlockSpec((1, hb, p, n), lambda ib, ih, ic: (ib, ih, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, s, h, p), x.dtype),
+            jax.ShapeDtypeStruct((bsz, h, s, p), x.dtype),
             jax.ShapeDtypeStruct((bsz, h, p, n), jnp.float32),
         ],
         scratch_shapes=[_vmem((hb, p, n), jnp.float32)],
-        # lint: allow(host-sync): trace-time backend probe — picks the
-        # interpret path off-TPU; retracing on backend change is intended
-        interpret=interpret or (jax.default_backend() != "tpu"),
-    )(x, dt, a, b_mat, c_mat, h0)
-    return y, hout
+        interpret=interpret,
+    )(xt, dtt[:, :, None, :], dtt[:, :, :, None], a.reshape(h, 1, 1),
+      b_mat[:, :, 0], c_mat[:, :, 0], h0)
+    return jnp.moveaxis(y, 1, 2), hout
 
 
 def _vmem(shape, dtype):

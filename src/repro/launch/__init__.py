@@ -1,1 +1,25 @@
 """Launchers: production mesh, multi-pod dry-run, train/serve drivers."""
+from __future__ import annotations
+
+import os
+from pathlib import Path
+
+# inside the checkout, never a temp name: the path is part of the cache key,
+# so a run finds what an earlier run compiled only at the same path
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is set here; otherwise the cache lives at
+    :data:`COMPILE_CACHE_DIR`.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)

@@ -171,8 +171,6 @@ def run_cell(
     t_compile = time.time() - t0
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):     # older jax: one dict per device
-        cost = cost[0] if cost else {}
     mem = None
     try:
         ma = compiled.memory_analysis()

@@ -1,7 +1,7 @@
 """Serving driver: multi-pod engine with the Lilac locality router.
 
-Real decode on host devices (RealBackend) for smoke-scale models, or the
-roofline-priced SimBackend for full assigned-architecture configs:
+Real decode on the process's devices (RealBackend), or the roofline-priced
+SimBackend for full assigned-architecture configs:
 
     PYTHONPATH=src python -m repro.launch.serve --arch glm4-9b --preset smoke \
         --pods 2 --requests 64
@@ -17,10 +17,60 @@ import numpy as np
 
 from repro.configs import get_config, get_smoke_config
 from repro.dist.locality import ROUTER_DEFAULTS
+from repro.launch import enable_compile_cache
 from repro.models import decoder
 from repro.models.common import init_params
 from repro.serve.engine import MultiPodEngine, RealBackend, Request, SimBackend
 from repro.serve.router import ARBITRATIONS, LocalityRouter
+
+
+def build_engine(cfg, *, backend: str = "real", pods: int = 2,
+                 policy: str = ROUTER_DEFAULTS.policy,
+                 arbitration: str = ROUTER_DEFAULTS.arbitration,
+                 sessions: int = 16, max_len: int = 256, seq_axis: int = 0,
+                 plan_epoch_ms: float = 0.0, seed: int = 0, trace=None,
+                 devices=None) -> MultiPodEngine:
+    """The serving stack for ``cfg``: backend, router, planner, engine.
+
+    ``backend="real"`` initializes seeded params in ``cfg.dtype`` and gives
+    every pod ``max(8, sessions)`` KV slots of ``max_len`` tokens; with
+    ``devices`` pod ``p`` runs on ``devices[p]`` as a one-chip replica.
+    """
+    if backend == "real":
+        mesh = None
+        seq_name = None
+        if seq_axis > 0:
+            from repro.launch.mesh import make_host_mesh
+            mesh = make_host_mesh(model=1, seq=seq_axis)
+            if "seq" in mesh.axis_names:
+                seq_name = "seq"
+        ctx = decoder.RunCtx(mesh=mesh, batch_axes=("data",),
+                             use_kernel="auto", seq_axis=seq_name)
+        params = init_params(cfg, jax.random.PRNGKey(seed),
+                             dtype=cfg.compute_dtype())
+        be = RealBackend(cfg, ctx, params, n_pods=pods,
+                         n_slots=max(8, sessions), max_len=max_len,
+                         devices=devices)
+        # the bytes one token adds to a session's cache column, from the
+        # stores themselves: what an acquire really ships per token
+        kv_per_tok = be.stores[0].nbytes_session() / max_len
+        seq_shards = be.seq_shards
+    else:
+        be = SimBackend(cfg)
+        kv_per_tok = (2.0 * 2 * cfg.n_kv_heads * cfg.head_dim * cfg.n_layers
+                      if cfg.n_kv_heads else 4096.0 * cfg.n_layers)
+        seq_shards = max(1, seq_axis)
+
+    router = LocalityRouter(pods, policy=policy, arbitration=arbitration,
+                            kv_bytes_per_token=kv_per_tok,
+                            seq_shards=seq_shards)
+    planner = None
+    if plan_epoch_ms > 0:
+        from repro.dist.sharding import make_plan_mesh
+        from repro.plan import PlacementPlanner
+        planner = PlacementPlanner.for_serving(
+            pods, sessions, epoch_ms=plan_epoch_ms, mesh=make_plan_mesh())
+    return MultiPodEngine(pods, be, router, planner=planner, trace=trace)
 
 
 def main(argv=None) -> dict:
@@ -53,6 +103,7 @@ def main(argv=None) -> dict:
                          "planner epochs, MoE dispatch verdicts) and export "
                          "Perfetto trace_event JSON here")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     recorder = None
     if args.trace:
@@ -68,40 +119,13 @@ def main(argv=None) -> dict:
     if not cfg.causal:
         raise SystemExit(f"{args.arch} is encoder-only: no decode serving")
 
-    if args.backend == "real":
-        mesh = None
-        seq_axis = None
-        if args.seq_axis > 0:
-            from repro.launch.mesh import make_host_mesh
-            mesh = make_host_mesh(model=1, seq=args.seq_axis)
-            if "seq" in mesh.axis_names:
-                seq_axis = "seq"
-        ctx = decoder.RunCtx(mesh=mesh, batch_axes=("data",),
-                             use_kernel="auto", seq_axis=seq_axis)
-        params = init_params(cfg, jax.random.PRNGKey(args.seed))
-        backend = RealBackend(cfg, ctx, params, n_pods=args.pods,
-                              n_slots=max(8, args.sessions), max_len=args.max_len)
-        kv_per_tok = 256.0
-        seq_shards = backend.seq_shards
-    else:
-        backend = SimBackend(cfg)
-        kv_per_tok = (2.0 * 2 * cfg.n_kv_heads * cfg.head_dim * cfg.n_layers
-                      if cfg.n_kv_heads else 4096.0 * cfg.n_layers)
-        seq_shards = max(1, args.seq_axis)
-
-    router = LocalityRouter(args.pods, policy=args.policy,
-                            arbitration=args.arbitration,
-                            kv_bytes_per_token=kv_per_tok,
-                            seq_shards=seq_shards)
-    planner = None
-    if args.plan_epoch_ms > 0:
-        from repro.dist.sharding import make_plan_mesh
-        from repro.plan import PlacementPlanner
-        planner = PlacementPlanner.for_serving(
-            args.pods, args.sessions, epoch_ms=args.plan_epoch_ms,
-            mesh=make_plan_mesh())
-    eng = MultiPodEngine(args.pods, backend, router, planner=planner,
-                         trace=recorder)
+    eng = build_engine(
+        cfg, backend=args.backend, pods=args.pods, policy=args.policy,
+        arbitration=args.arbitration, sessions=args.sessions,
+        max_len=args.max_len, seq_axis=args.seq_axis,
+        plan_epoch_ms=args.plan_epoch_ms, seed=args.seed, trace=recorder)
+    router, planner = eng.router, eng.planner
+    seq_shards = router.seq_shards
     rng = np.random.default_rng(args.seed)
     submitted = 0
     while submitted < args.requests:

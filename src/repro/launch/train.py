@@ -25,6 +25,7 @@ import numpy as np
 from repro.configs import get_config, get_smoke_config
 from repro.data import DataConfig, SyntheticLM
 from repro.dist import sharding as shd
+from repro.launch import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import decoder
 from repro.models.common import init_params
@@ -83,6 +84,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = scaled_config(args.arch, args.preset)
     mesh = make_host_mesh()

@@ -10,7 +10,7 @@ caller.  Three entry points per mixer:
 
 The inner attention product dispatches to the Pallas flash kernel on TPU
 (``repro.kernels.flash_attention``) and to the fused-mask jnp reference on
-other backends (and always under ``interpret`` tests).
+other backends; :func:`repro.kernels.kernel_mode` makes that choice.
 """
 from __future__ import annotations
 
@@ -115,16 +115,17 @@ def sdpa(
 ) -> jax.Array:
     """Scaled dot-product attention with GQA + optional flash kernel."""
     scale = scale if scale is not None else q.shape[-1] ** -0.5
-    if use_kernel == "auto":
-        use_kernel = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if use_kernel == "pallas" and q.shape[1] > 1:
+    from repro.kernels import kernel_mode
+
+    interpret = kernel_mode(use_kernel)
+    if interpret is not None and q.shape[1] > 1:
         from repro.kernels import flash_attention as fa
 
         return fa.flash_attention(
             q, k, v,
             q_positions=q_positions, kv_positions=kv_positions,
             causal=causal, sliding_window=sliding_window,
-            logit_softcap=logit_softcap, scale=scale,
+            logit_softcap=logit_softcap, scale=scale, interpret=interpret,
         )
     mask = attn_mask(q_positions, kv_positions, causal, sliding_window)
     return _sdpa_ref(q, k, v, mask, scale, logit_softcap)

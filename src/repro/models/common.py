@@ -16,6 +16,7 @@ stubs (qwen2-vl / hubert).
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from dataclasses import dataclass
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -455,6 +456,14 @@ def param_shapes(cfg: ModelConfig, model_size: int = 1) -> Dict[str, Any]:
     return tree
 
 
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _truncated_normal(key, shape, std, dtype):
+    # one fused program per leaf: the float32 draw never materializes, so a
+    # bf16 tree initializes within the device memory it will occupy
+    return (jax.random.truncated_normal(key, -3, 3, shape, jnp.float32)
+            * std).astype(dtype)
+
+
 def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32,
                 model_size: int = 1) -> Dict[str, Any]:
     shapes = param_shapes(cfg, model_size)
@@ -467,8 +476,8 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32,
         if len(shape) == 1 or (len(shape) == 2 and shape[-1] in (1,)):
             return jnp.zeros(shape, dtype)
         fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
-        std = 1.0 / math.sqrt(fan_in)
-        return (jax.random.truncated_normal(k, -3, 3, shape, jnp.float32) * std).astype(dtype)
+        return _truncated_normal(k, tuple(shape), 1.0 / math.sqrt(fan_in),
+                                 jnp.dtype(dtype))
 
     params = treedef.unflatten([init_one(s, k) for s, k in zip(leaves, keys)])
     # special inits

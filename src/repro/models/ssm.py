@@ -206,12 +206,14 @@ def mamba2_block(
         bm = b_mat.reshape(bsz, seq, s.n_groups, s.d_state)
         cm = c_mat.reshape(bsz, seq, s.n_groups, s.d_state)
         h0 = cache["ssm"] if cache is not None else None
-        if use_kernel == "auto":
-            use_kernel = "pallas" if jax.default_backend() == "tpu" else "ref"
-        if use_kernel == "pallas":
+        from repro.kernels import kernel_mode
+
+        interpret = kernel_mode(use_kernel)
+        if interpret is not None:
             from repro.kernels import ssd_scan as ssd_k
 
-            y_h, final = ssd_k.ssd_scan(xh, dt, a, bm, cm, chunk=s.chunk, h0=h0)
+            y_h, final = ssd_k.ssd_scan(xh, dt, a, bm, cm, chunk=s.chunk,
+                                        h0=h0, interpret=interpret)
         else:
             pad = (-seq) % s.chunk
             if pad:

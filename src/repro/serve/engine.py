@@ -3,7 +3,8 @@
 Two backends behind one engine:
 
 * :class:`RealBackend` — actually decodes with the JAX model (per-session
-  positions, KV slots); used by the runnable example on host devices.
+  positions, KV slots) on the process's devices: every pod on the default
+  device, or each pod on a chip of its own as a one-chip replica.
 * :class:`SimBackend` — prices each pod-step with the roofline model;
   used by the pod-scale benchmarks where 256-chip pods are simulated.
 
@@ -85,10 +86,16 @@ class SimBackend:
 
 
 class RealBackend:
-    """Actual JAX decode on host devices (one KVStore per pod)."""
+    """Actual JAX decode, one KVStore per pod.
+
+    ``devices`` places pod ``p`` on ``devices[p]``: its KVStore and a
+    replica of the params live on that device, so the pods are one-chip
+    replicas behind the router and a KV acquire crosses chips.  Without it
+    every pod shares the default device and one params tree.
+    """
 
     def __init__(self, cfg, ctx, params, n_pods: int, n_slots: int,
-                 max_len: int) -> None:
+                 max_len: int, devices=None) -> None:
         import jax
         import jax.numpy as jnp
 
@@ -96,15 +103,27 @@ class RealBackend:
         from .kvcache import KVStore
 
         self.cfg, self.ctx, self.params = cfg, ctx, params
+        if devices is None:
+            self.devices = [None] * n_pods
+            self.pod_params = [params] * n_pods
+        else:
+            if len(devices) < n_pods:
+                raise ValueError(
+                    f"{n_pods} pods need {n_pods} devices, got {len(devices)}")
+            self.devices = list(devices[:n_pods])
+            self.pod_params = [jax.device_put(params, d)
+                               for d in self.devices]
         self.stores = [
-            KVStore(cfg, n_slots, max_len, mesh=getattr(ctx, "mesh", None))
-            for _ in range(n_pods)
+            KVStore(cfg, n_slots, max_len, mesh=getattr(ctx, "mesh", None),
+                    device=d)
+            for d in self.devices
         ]
         # seq shards per pod mesh: the engine re-prices actual-byte state
         # moves with this, so a seq-sharded migration charges 1/seq_shards
         # of the bytes per hop
         self.seq_shards = self.stores[0].seq_shards
         self._jnp = jnp
+        self._put = jax.device_put
 
         def step(params, caches, tokens, pos):
             return decoder.decode_step(cfg, ctx, params, caches, tokens, pos)
@@ -146,8 +165,10 @@ class RealBackend:
             s = st.sessions[sid]
             tokens[s.slot] = s.last_token
             pos[s.slot] = s.length
+        dev = self.devices[pod]
         logits, st.caches = self._step(
-            self.params, st.caches, jnp.asarray(tokens), jnp.asarray(pos))
+            self.pod_params[pod], st.caches, self._put(tokens, dev),
+            self._put(pos, dev))
         nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
         out = {}
         for sid in sids:

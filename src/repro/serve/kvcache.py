@@ -4,7 +4,8 @@ The engine decodes a *batch* of sessions at once; each session owns a slot
 in the batched cache trees produced by ``decoder.init_cache``.  Slots are
 recycled; session → slot indirection lives here.  ``export_session`` /
 ``import_session`` move one session's cache column between pods (the
-"migrate state" branch of the locality router).
+"migrate state" branch of the locality router), across chips when the pods
+live on different devices.
 """
 from __future__ import annotations
 
@@ -47,14 +48,18 @@ def _map_with_bdim(fn, tree: Dict[str, Any], *rest: Dict[str, Any]):
 
 class KVStore:
     def __init__(self, cfg: ModelConfig, n_slots: int, max_len: int,
-                 dtype=jnp.bfloat16, *, mesh=None) -> None:
+                 dtype=jnp.bfloat16, *, mesh=None, device=None) -> None:
         self.cfg = cfg
         self.n_slots = n_slots
         self.max_len = max_len
         self.mesh = mesh
+        # the one device holding this pod's caches (None: the default
+        # device); imported columns are moved here before they land
+        self.device = device
         self._shardings = None
         self._pspecs = None
-        self.caches = decoder.init_cache(cfg, n_slots, max_len, dtype)
+        with jax.default_device(device):
+            self.caches = decoder.init_cache(cfg, n_slots, max_len, dtype)
         if mesh is not None:
             # place the slot-ring trees per the ownership ledger, so imported
             # sessions land pre-sharded on this pod's mesh
@@ -139,6 +144,11 @@ class KVStore:
         s = self.alloc(blob["sid"])
         s.length = blob["length"]
         s.last_token = blob["last_token"]
+        tree = blob["tree"]
+        if self.device is not None:
+            # the column left another pod's chip: land it on this one
+            # before the scatter, which runs where its operands live
+            tree = jax.device_put(tree, self.device)
 
         def put(bdim, dst, src):
             idx = [slice(None)] * dst.ndim
@@ -147,7 +157,7 @@ class KVStore:
             src_idx[bdim] = 0
             return dst.at[tuple(idx)].set(src[tuple(src_idx)].astype(dst.dtype))
 
-        self.caches = _map_with_bdim(put, self.caches, blob["tree"])
+        self.caches = _map_with_bdim(put, self.caches, tree)
         if self._shardings is not None:
             # re-place the updated trees on this pod's mesh: an imported
             # long-context column lands seq-sharded instead of wherever the

@@ -74,8 +74,7 @@ def compressed_psum(g: jax.Array, residual: jax.Array, axis: str):
     int8 payloads are summed in int32 (the all-reduce moves 1B/elem +
     one f32 scale), and the mean is rebuilt with the max scale.
     """
-    # psum of ones == axis size (jax.lax.axis_size only exists on newer jax)
-    n = jax.lax.psum(jnp.ones((), jnp.float32), axis)
+    n = jax.lax.axis_size(axis)
     c, new_res = compress(g, residual)
     # use the max scale across shards so the int32 sum is consistent
     scale = jax.lax.pmax(c.scale, axis)

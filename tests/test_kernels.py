@@ -34,7 +34,8 @@ def test_flash_attention_vs_ref(b, sq, skv, hq, hkv, dk, dv, causal, window,
     kp = jnp.broadcast_to(jnp.arange(skv, dtype=jnp.int32)[None], (b, skv))
     out = flash_attention(q, k, v, q_positions=qp, kv_positions=kp,
                           causal=causal, sliding_window=window,
-                          logit_softcap=cap, block_q=64, block_k=64)
+                          logit_softcap=cap, block_q=64, block_k=64,
+                          interpret=True)
     want = ref.sdpa_ref(q, k, v, q_positions=qp, kv_positions=kp,
                         causal=causal, sliding_window=window, logit_softcap=cap)
     tol = 5e-2 if dtype == jnp.bfloat16 else 2e-5
@@ -58,7 +59,8 @@ def test_ssd_scan_vs_ref(b, s, h, p, n, chunk, hb):
     bm = jnp.asarray(RNG.standard_normal((b, s, 1, n)) * 0.4, jnp.float32)
     cm = jnp.asarray(RNG.standard_normal((b, s, 1, n)) * 0.4, jnp.float32)
     h0 = jnp.asarray(RNG.standard_normal((b, h, p, n)) * 0.1, jnp.float32)
-    y_k, f_k = ssd_scan(x, dt, a, bm, cm, chunk=chunk, h0=h0, block_heads=hb)
+    y_k, f_k = ssd_scan(x, dt, a, bm, cm, chunk=chunk, h0=h0, block_heads=hb,
+                        interpret=True)
     y_r, f_r = ref.ssd_ref(x, dt, a, bm, cm, chunk=chunk, h0=h0)
     scale = float(jnp.max(jnp.abs(y_r))) + 1e-9
     assert float(jnp.max(jnp.abs(y_k - y_r))) / scale < 2e-5
@@ -87,12 +89,12 @@ def test_ssd_decode_recurrence_matches_scan():
                                atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("B,R,W,n_items,chunk,bt", [
+@pytest.mark.parametrize("B,R,W,n_items,chunk,te", [
     (64, 8, 4, 1024, 256, 32),
     (200, 16, 8, 5000, 512, 64),
     (7, 3, 2, 100, 64, 8),
 ])
-def test_lease_validate_vs_ref(B, R, W, n_items, chunk, bt):
+def test_lease_validate_vs_ref(B, R, W, n_items, chunk, te):
     store = jnp.asarray(RNG.integers(0, 50, n_items), jnp.int32)
     locks = jnp.asarray(RNG.random(n_items) < 0.05, jnp.int32)
     items = jnp.asarray(RNG.integers(-1, n_items, (B, R)), jnp.int32)
@@ -101,7 +103,7 @@ def test_lease_validate_vs_ref(B, R, W, n_items, chunk, bt):
                      jnp.asarray(RNG.integers(0, 50, (B, R)), jnp.int32))
     witems = jnp.asarray(RNG.integers(-1, n_items, (B, W)), jnp.int32)
     got = lease_validate(store, items, vers, locks, witems,
-                         block_txns=bt, chunk=chunk)
+                         block_entries=te, chunk=chunk, interpret=True)
     want = ref.lease_validate_ref(store, items, vers, locks > 0, witems)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -153,7 +155,8 @@ def test_stm_batched_validation_matches_kernel():
     kern = lease_validate(
         jnp.asarray(store.versions, jnp.int32), jnp.asarray(items),
         jnp.asarray(vers), jnp.zeros((500,), jnp.int32),
-        jnp.full((len(txns), 1), -1, jnp.int32), block_txns=16, chunk=128)
+        jnp.full((len(txns), 1), -1, jnp.int32), block_entries=16, chunk=128,
+        interpret=True)
     np.testing.assert_array_equal(np.asarray(kern), loop)
 
 

@@ -315,6 +315,52 @@ def test_engine_seq_shards_reprices_real_transfers():
     assert backend.stores[0].seq_shards == 1
 
 
+def test_build_engine_pods_on_devices_decode_like_shared_device():
+    """build_engine with ``devices`` (each pod a replica on its own device)
+    serves a stream with an acquire and forwards to the same greedy tokens
+    as the pods sharing the default device; imported columns land on the
+    destination pod's device and bf16 params come out of init."""
+    from repro.launch.serve import build_engine
+
+    cfg = get_smoke_config("glm4-9b")
+    dev = jax.devices()[0]
+
+    def serve(devices):
+        eng = build_engine(cfg, pods=2, sessions=8, max_len=32,
+                           devices=devices)
+        out = {}
+        step = eng.backend.step
+
+        def recording(pod, sids):
+            toks = step(pod, sids)
+            for sid, t in toks.items():
+                out.setdefault(sid, []).append(t)
+            return toks
+
+        eng.backend.step = recording
+        # 24 tokens of cache outweigh a request's bytes: later remote
+        # requests forward instead of acquiring
+        for sid, origin in [(0, 0), (1, 1), (1, 0), (2, 0)]:
+            eng.submit(Request(sid=sid, origin=origin, n_tokens=24))
+        eng.drain()
+        for sid, origin in [(0, 1), (2, 1), (1, 1)]:
+            eng.submit(Request(sid=sid, origin=origin, n_tokens=2))
+        eng.drain()
+        return eng, out
+
+    shared, want = serve(None)
+    placed, got = serve([dev, dev])
+    assert got == want and sum(map(len, got.values())) > 0
+    assert placed.router.metrics.acquires >= 1
+    assert placed.metrics.forwards >= 1
+    for st in placed.backend.stores:
+        assert {d for x in jax.tree.leaves(st.caches)
+                for d in x.devices()} == {dev}
+    assert all(x.dtype == jnp.bfloat16
+               for x in jax.tree.leaves(shared.backend.params)
+               if jnp.issubdtype(x.dtype, jnp.floating))
+
+
 def test_router_freq_decays_with_clock():
     """Session-touch rates decay on the router clock (tick), so the LC
     attractor is rate-based: old bursts fade once time passes.  Rates live
