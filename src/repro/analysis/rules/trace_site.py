@@ -11,6 +11,11 @@ they are only evaluated inside the enabled branch.
 The rule fires on ``<recv>.span/instant/abegin/aend/counter(...)`` calls
 whose receiver reads like a trace recorder (``tr``, anything containing
 ``trace``) and whose first positional argument is not a string literal.
+
+Host-clock spans (``host_span(...)``, ``<clock>.host_span(...)``) land in
+the profiler's trace beside every other library's annotations, and the
+benchmark's readers select them by prefix: their name must be a string
+literal that starts with ``repro.``.
 """
 from __future__ import annotations
 
@@ -20,6 +25,8 @@ from typing import List
 from ..lint import FileCtx, Violation
 
 RECORDER_METHODS = {"span", "instant", "abegin", "aend", "counter"}
+HOST_SPAN = "host_span"
+HOST_PREFIX = "repro."
 
 
 def _recv_text(e: ast.expr) -> str:
@@ -46,6 +53,11 @@ class Rule:
             if not isinstance(node, ast.Call):
                 continue
             f = node.func
+            if _is_host_span(f):
+                v = self._check_host_span(ctx, node)
+                if v is not None:
+                    out.append(v)
+                continue
             if not isinstance(f, ast.Attribute) \
                     or f.attr not in RECORDER_METHODS:
                 continue
@@ -65,6 +77,25 @@ class Rule:
                 f"({kind}) — pass a string literal; put variability in "
                 f"the track or payload"))
         return out
+
+    def _check_host_span(self, ctx: FileCtx, node: ast.Call):
+        first = node.args[0] if node.args else None
+        if isinstance(first, ast.Constant) and isinstance(first.value, str) \
+                and first.value.startswith(HOST_PREFIX):
+            return None
+        what = ("no name" if first is None else
+                f"{first.value!r}" if isinstance(first, ast.Constant)
+                else "f-string" if isinstance(first, ast.JoinedStr)
+                else type(first).__name__)
+        return ctx.violation(
+            node, self.id,
+            f"host_span() needs a string literal name starting "
+            f"{HOST_PREFIX!r}, got {what} — put variability in its args")
+
+
+def _is_host_span(f: ast.expr) -> bool:
+    return (isinstance(f, ast.Name) and f.id == HOST_SPAN) or \
+        (isinstance(f, ast.Attribute) and f.attr == HOST_SPAN)
 
 
 RULE = Rule()

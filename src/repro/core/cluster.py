@@ -34,6 +34,8 @@ from typing import Callable, Dict, FrozenSet, List, Optional, Tuple
 
 import numpy as np
 
+from repro.obs.trace import host_span
+
 from .conflict import ConflictClassMap
 from .dtd import DTD, DTDConfig
 from .events import EventQueue
@@ -330,7 +332,10 @@ class Cluster:
             )
 
     def _make_handler(self, node: int, fn):
-        return lambda msg, sender, n=node, f=fn: f(n, msg, sender)
+        def deliver(msg, sender, n=node, f=fn):
+            with host_span("repro.tm.deliver"):
+                f(n, msg, sender)
+        return deliver
 
     # -- lifecycle -----------------------------------------------------------
     def run(self) -> Metrics:
@@ -591,7 +596,8 @@ class Cluster:
     def _exec_done(self, txn: SimTxn, node: int) -> None:
         r = self.replicas[node]
         txn.stm = Transaction(txid=txn.txid, origin=txn.origin)
-        txn.result = txn.spec.execute(r.store, txn.stm)
+        with host_span("repro.tm.execute"):
+            txn.result = txn.spec.execute(r.store, txn.stm)
         self._release_slot(node)
         txn.exec_done = True
         tr = self.trace
@@ -796,10 +802,10 @@ class Cluster:
         """
         if self._item_cc is None:
             return None
-        lm = self.replicas[node].lm
-        owners = lm.owner_np()
-        per_item = owners[self._item_cc]
-        return ((per_item >= 0) & (per_item != node)).astype(np.int32)
+        with host_span("repro.tm.write_locks"):
+            owners = self.replicas[node].lm.owner_np()
+            per_item = owners[self._item_cc]
+            return ((per_item >= 0) & (per_item != node)).astype(np.int32)
 
     def _locked_write(self, txn: SimTxn, node: int) -> bool:
         """Per-txn twin of the kernels' lock check (small-batch path)."""
@@ -818,6 +824,12 @@ class Cluster:
         batch, r.certify_queue = r.certify_queue, []
         if not batch:
             return
+        with host_span("repro.tm.certify", node=node, batch=len(batch)):
+            self._certify_batch(node, batch)
+
+    def _certify_batch(self, node: int, batch: List[SimTxn]) -> None:
+        """Certify one drained batch, apply its committers, settle each."""
+        r = self.replicas[node]
         locks = self._write_locks(node)
         if len(batch) >= self.cfg.certify_jax_min:
             ok = validate_batch(r.store, [t.stm for t in batch], locks=locks)
@@ -844,6 +856,11 @@ class Cluster:
         # written by an earlier committer in the same batch must abort (the
         # earlier commit stamped a fresh txid, which can never equal the
         # snapshot version).  Writes are resolved by the single apply_batch.
+        with host_span("repro.tm.apply"):
+            self._apply_batch(node, batch, ok)
+
+    def _apply_batch(self, node: int, batch: List[SimTxn], ok) -> None:
+        r = self.replicas[node]
         written: set = set()
         verdicts: List[bool] = []
         committers: List[SimTxn] = []
@@ -913,7 +930,8 @@ class Cluster:
     def _reexec_done(self, txn: SimTxn, node: int) -> None:
         r = self.replicas[node]
         txn.stm = Transaction(txid=txn.txid, origin=txn.origin)
-        txn.result = txn.spec.execute(r.store, txn.stm)
+        with host_span("repro.tm.execute"):
+            txn.result = txn.spec.execute(r.store, txn.stm)
         self._release_slot(node)
         tr = self.trace
         if tr is not None:
@@ -926,8 +944,9 @@ class Cluster:
 
     def _commit(self, txn: SimTxn, node: int) -> None:
         r = self.replicas[node]
-        r.store.apply_versioned(txn.stm.write_set, txn.txid)
-        self._commit_applied(txn, node)
+        with host_span("repro.tm.apply"):
+            r.store.apply_versioned(txn.stm.write_set, txn.txid)
+            self._commit_applied(txn, node)
 
     def _commit_applied(self, txn: SimTxn, node: int) -> None:
         """Post-apply commit work: disseminate the write-set, free leases.
@@ -1041,7 +1060,8 @@ class Cluster:
         elif kind == "commit":
             c = payload
             if node != c["executed_on"]:
-                r.store.apply_versioned(c["writes"], c["txid"])
+                with host_span("repro.tm.apply"):
+                    r.store.apply_versioned(c["writes"], c["txid"])
             r.freq.record(self.events.now, c["origin"], c["ccs"])
             if node == c["origin"]:
                 # resume the originating thread (result piggybacked on the

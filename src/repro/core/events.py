@@ -140,6 +140,10 @@ class EventQueue:
 
     def run(self, until: float, max_events: Optional[int] = None) -> None:
         """Dispatch events in order until simulated ``until`` time."""
+        with obs_trace.host_span("repro.tm.events"):
+            self._run(until, max_events)
+
+    def _run(self, until: float, max_events: Optional[int]) -> None:
         n = 0
         policy = self.policy
         while self._heap and self._heap[0].time <= until:

@@ -35,6 +35,8 @@ from typing import Dict, FrozenSet, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs.trace import NO_SPAN, host_span
+
 from .lease import LeaseRequest
 
 
@@ -432,6 +434,10 @@ class ShardedLeaseManager:
         kernel when ``use_kernel`` (callers gate on ``jax_min``), else the
         numpy twin — the two agree bitwise (tests pin it).
         """
+        with (host_span("repro.tm.settle") if use_kernel else NO_SPAN):
+            return self._settle(groups, fresh_ccs, use_kernel)
+
+    def _settle(self, groups, fresh_ccs, use_kernel):
         if fresh_ccs is None:
             fresh_ccs = np.empty((0,), np.int32)
         fresh_ccs = np.asarray(fresh_ccs, np.int32)
@@ -467,8 +473,9 @@ class ShardedLeaseManager:
             owner, free, enabled = ops.settle_lease_batch(
                 head_req, head_proc, head_active, qlen, fresh,
                 wait_req, wait_cc, self.proc)
-            return (rel, np.asarray(owner), np.asarray(free),
-                    np.asarray(enabled)[:B])
+            with host_span("repro.readback"):
+                return (rel, np.asarray(owner), np.asarray(free),
+                        np.asarray(enabled)[:B])
         owner, free, enabled = _settle_np(
             head_req, head_proc, head_active, qlen, fresh,
             wait_req, wait_cc, self.proc)

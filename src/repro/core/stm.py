@@ -21,6 +21,8 @@ from typing import Dict, Iterable, List, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs.trace import host_span
+
 
 @dataclass
 class ReadSetEntry:
@@ -303,27 +305,30 @@ def validate_batch(store: VersionedStore, txns: Sequence[Transaction],
         return np.zeros((0,), dtype=bool)
     from repro.kernels.ops import validate_transactions
 
-    items, vers = pack_read_sets(txns)
-    # without locks every write check passes — skip the write packing and
-    # let the kernel mask an empty [B, 1] column
-    witems = pack_write_sets(txns) if locks is not None else None
-    # bucket the row count too: the jit'd kernels are shape-specialized,
-    # and drain sizes vary every instant — padded rows are all-masked
-    # (items -1) and certify True, sliced off below
     b = len(txns)
-    bp = _pad_bucket(b)
-    if bp != b:
-        items = np.pad(items, ((0, bp - b), (0, 0)), constant_values=-1)
-        vers = np.pad(vers, ((0, bp - b), (0, 0)))
-        if witems is not None:
-            witems = np.pad(witems, ((0, bp - b), (0, 0)),
-                            constant_values=-1)
+    with host_span("repro.stm.pack", batch=b):
+        items, vers = pack_read_sets(txns)
+        # without locks every write check passes — skip the write packing
+        # and let the kernel mask an empty [B, 1] column
+        witems = pack_write_sets(txns) if locks is not None else None
+        # bucket the row count too: the jit'd kernels are shape-specialized,
+        # and drain sizes vary every instant — padded rows are all-masked
+        # (items -1) and certify True, sliced off below
+        bp = _pad_bucket(b)
+        if bp != b:
+            items = np.pad(items, ((0, bp - b), (0, 0)), constant_values=-1)
+            vers = np.pad(vers, ((0, bp - b), (0, 0)))
+            if witems is not None:
+                witems = np.pad(witems, ((0, bp - b), (0, 0)),
+                                constant_values=-1)
+        versions = store.versions.astype(np.int32)  # beats a device cast
     out = validate_transactions(
-        store.versions.astype(np.int32),     # numpy cast beats device cast
+        versions,
         items,
         vers,
         write_locks=locks,
         write_items=witems,
         backend=backend,
     )
-    return np.asarray(out[:b])
+    with host_span("repro.readback"):
+        return np.asarray(out[:b])

@@ -11,6 +11,9 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from repro.obs.trace import host_span
 
 from . import kernel_mode, ref
 from .flash_attention import flash_attention as _flash
@@ -63,11 +66,15 @@ def settle_lease_batch(head_req, head_proc, head_active, qlen, fresh_blocked,
     path, and the hook point for a TPU kernel later).
     """
     del backend  # single jit'd path for now; kept for API symmetry
-    return _lease_settle_jit(
-        jnp.asarray(head_req, jnp.int32), jnp.asarray(head_proc, jnp.int32),
-        jnp.asarray(head_active, jnp.int32), jnp.asarray(qlen, jnp.int32),
-        jnp.asarray(fresh_blocked, bool), jnp.asarray(wait_req, jnp.int32),
-        jnp.asarray(wait_cc, jnp.int32), jnp.int32(proc))
+    with host_span("repro.ops.settle", h2d_bytes=lambda: _h2d_bytes(
+            head_req, head_proc, head_active, qlen, fresh_blocked,
+            wait_req, wait_cc)):
+        return _lease_settle_jit(
+            jnp.asarray(head_req, jnp.int32),
+            jnp.asarray(head_proc, jnp.int32),
+            jnp.asarray(head_active, jnp.int32), jnp.asarray(qlen, jnp.int32),
+            jnp.asarray(fresh_blocked, bool), jnp.asarray(wait_req, jnp.int32),
+            jnp.asarray(wait_cc, jnp.int32), jnp.int32(proc))
 
 
 def moe_combine(back, tok_slot, gate_slot, *, tp: int, capacity: int,
@@ -100,17 +107,28 @@ def validate_transactions(
     (``repro.serve.certifier``) go through.  Write locks default to none
     (all zeros); both backends honor them identically.
     """
-    b = read_items.shape[0]
-    store_versions = jnp.asarray(store_versions, jnp.int32)
-    if write_locks is None:
-        write_locks = jnp.zeros_like(store_versions)
-    else:
-        write_locks = jnp.asarray(write_locks, jnp.int32)
-    if write_items is None:
-        write_items = jnp.full((b, 1), -1, jnp.int32)
-    interpret = kernel_mode(backend)
-    if interpret is not None:
-        return _lease_validate(store_versions, read_items, read_versions,
-                               write_locks, write_items, interpret=interpret)
-    return _lease_validate_ref_jit(store_versions, read_items, read_versions,
-                                   write_locks, write_items)
+    with host_span("repro.ops.validate", h2d_bytes=lambda: _h2d_bytes(
+            store_versions, read_items, read_versions, write_locks,
+            write_items)):
+        b = read_items.shape[0]
+        store_versions = jnp.asarray(store_versions, jnp.int32)
+        if write_locks is None:
+            write_locks = jnp.zeros_like(store_versions)
+        else:
+            write_locks = jnp.asarray(write_locks, jnp.int32)
+        if write_items is None:
+            write_items = jnp.full((b, 1), -1, jnp.int32)
+        interpret = kernel_mode(backend)
+        if interpret is not None:
+            return _lease_validate(store_versions, read_items, read_versions,
+                                   write_locks, write_items,
+                                   interpret=interpret)
+        return _lease_validate_ref_jit(store_versions, read_items,
+                                       read_versions, write_locks,
+                                       write_items)
+
+
+def _h2d_bytes(*arrays) -> int:
+    """Bytes of the host (numpy) arrays among ``arrays``: what a dispatch
+    uploads to the device."""
+    return sum(a.nbytes for a in arrays if isinstance(a, np.ndarray))

@@ -1,33 +1,28 @@
-"""Counters, gauges, and quantile histograms behind one registry.
+"""Counters and quantile histograms behind one registry.
 
 The serving stack previously tracked everything as loose dataclass
 fields (``EngineMetrics``, ``CertifierMetrics``) which made per-pod
 breakdowns and latency distributions bolt-ons.  This module is the
 single source of truth those migrate onto:
 
-- :class:`Counter` / :class:`Gauge` — plain scalars with a name.
+- :class:`Counter` — a plain scalar with a name.
 - :class:`Histogram` — keeps **raw samples** (exact quantiles, numpy
-  'linear' interpolation semantics) plus pow2 log-bucket counts
-  ``[2^k, 2^(k+1))`` for cheap shape summaries, and an SLO-attainment
-  helper (fraction of samples ≤ limit).
+  'linear' interpolation semantics) and an SLO-attainment helper
+  (fraction of samples ≤ limit).
 - :class:`Registry` — name → metric, with ``as_dict()``.
 - :class:`MetricSet` — an attribute facade over a registry so existing
   call sites (``m.forwards += 1``) and tests keep working unchanged
   while the values live in the registry.
-- :class:`MonotonicSampler` — the one sanctioned wall-clock seam.  Sim
-  metrics are deterministic by construction; anything that *must* read
-  host time (planner scoring runs on the host CPU, so its wall block
-  time is real) goes through a sampler instance, which tests can swap
-  for a fake.  This keeps the ``event-determinism`` lint honest: no
-  bare ``time.*`` reads in step loops.
+
+Host wall time (planner blocking, measured decode) is read through
+:class:`repro.obs.trace.HostClock`, the one wall-clock seam, which adds
+it into a :class:`Counter` here.
 
 Everything here is stdlib-only; numpy is used nowhere so the registry
 can be imported from lint/CI contexts without heavyweight deps.
 """
 from __future__ import annotations
 
-import math
-import time
 from typing import Any, Dict, List, Optional
 
 
@@ -44,44 +39,21 @@ class Counter:
         self.value += n
 
 
-class Gauge:
-    """A named last-write-wins scalar."""
-
-    __slots__ = ("name", "value")
-
-    def __init__(self, name: str, value=0.0):
-        self.name = name
-        self.value = value
-
-    def set(self, v) -> None:
-        self.value = v
-
-
 class Histogram:
-    """Exact-quantile histogram with pow2 log-bucket counts.
+    """Exact-quantile histogram.
 
-    ``observe(v)`` appends the raw sample (quantiles stay exact — the
-    sample counts here are tool-scale, not telemetry-scale) and bumps
-    the log bucket ``k = floor(log2(v))``, i.e. bucket ``k`` covers
-    ``[2^k, 2^(k+1))``.  Non-positive samples land in the reserved
-    ``"le_zero"`` bucket.
+    ``observe(v)`` appends the raw sample: quantiles stay exact (the
+    sample counts here are tool-scale, not telemetry-scale).
     """
 
-    __slots__ = ("name", "samples", "buckets")
+    __slots__ = ("name", "samples")
 
     def __init__(self, name: str):
         self.name = name
         self.samples: List[float] = []
-        self.buckets: Dict[Any, int] = {}
 
     def observe(self, v: float, n: int = 1) -> None:
-        for _ in range(n):
-            self.samples.append(v)
-        if v > 0.0:
-            k = math.floor(math.log2(v))
-        else:
-            k = "le_zero"
-        self.buckets[k] = self.buckets.get(k, 0) + n
+        self.samples.extend([v] * n)
 
     @property
     def count(self) -> int:
@@ -126,12 +98,6 @@ class Registry:
         m = self._metrics.get(name)
         if m is None:
             m = self._metrics[name] = Counter(name, value)
-        return m
-
-    def gauge(self, name: str, value=0.0) -> Gauge:
-        m = self._metrics.get(name)
-        if m is None:
-            m = self._metrics[name] = Gauge(name, value)
         return m
 
     def histogram(self, name: str) -> Histogram:
@@ -198,28 +164,3 @@ class MetricSet:
     def as_dict(self) -> Dict[str, Any]:
         return {name: self.registry.counter(self._prefix + name).value
                 for name in type(self).FIELDS}
-
-
-class MonotonicSampler:
-    """The sanctioned host-clock read: ``elapsed = s.lap()`` pairs.
-
-    ``clock`` is injectable (tests pass a fake) and defaults to
-    ``time.perf_counter``.  Call :meth:`mark` to open an interval and
-    :meth:`lap` to close it and get the elapsed seconds.
-    """
-
-    __slots__ = ("_clock", "_t0")
-
-    def __init__(self, clock=time.perf_counter):
-        self._clock = clock
-        self._t0: Optional[float] = None
-
-    def mark(self) -> None:
-        self._t0 = self._clock()
-
-    def lap(self) -> float:
-        if self._t0 is None:
-            return 0.0
-        dt = self._clock() - self._t0
-        self._t0 = None
-        return dt

@@ -40,11 +40,26 @@ The module-level :data:`TRACE` singleton exists for call sites with no
 object to thread a recorder through (``models/moe.py``, the event queue's
 replay capture).  ``install()``/``uninstall()`` swap it; everything else
 threads explicit recorder instances.
+
+**Host-clock spans** are the other half, and the repository's one
+wall-clock seam.  :func:`host_span` writes a
+``jax.profiler.TraceAnnotation`` into the profiler's own trace, so the
+host work of the TM core and the serving step lands on the clock of the
+device planes, next to the device's idle gaps.  With the profiler off it
+returns the shared :data:`NO_SPAN` and evaluates none of its args.  It
+never returns a time, so nothing can branch on one and seeded runs stay
+reproducible.  :class:`HostClock` is the one place that reads the host
+clock for a metric: its ``host_span`` also adds the block's seconds into
+a counter (``plan_block_s``, the measured decode time).  Span names are
+static strings starting ``repro.`` (``event-trace-site`` lint).
 """
 from __future__ import annotations
 
 import json
+import time
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 # internal event tuple layout: (ph, name, track, ts_ms, dur_ms, aid, payload)
 _Event = Tuple[str, str, str, float, float, Any, Optional[Dict[str, Any]]]
@@ -97,6 +112,73 @@ def uninstall() -> None:
     """Restore the no-op singleton."""
     global TRACE
     TRACE = NULL
+
+
+class _NoSpan:
+    """The shared do-nothing context :func:`host_span` returns when the
+    profiler is not recording."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+NO_SPAN = _NoSpan()
+_recording = TraceAnnotation.is_enabled
+
+
+def host_span(name: str, **args):
+    """A host span on the profiler's clock, or :data:`NO_SPAN`.
+
+    ``name`` is a static string starting ``repro.``.  An arg given as a
+    callable is called for its value, and only while the profiler
+    records, so ``h2d_bytes=lambda: ...`` costs nothing when it is off.
+    """
+    if not _recording():
+        return NO_SPAN
+    if args:
+        return TraceAnnotation(name, **{k: v() if callable(v) else v
+                                        for k, v in args.items()})
+    return TraceAnnotation(name)
+
+
+class _TimedSpan:
+    __slots__ = ("_clock", "_span", "_into", "_t0")
+
+    def __init__(self, clock, span, into) -> None:
+        self._clock, self._span, self._into = clock, span, into
+
+    def __enter__(self) -> None:
+        self._span.__enter__()
+        self._t0 = self._clock()
+
+    def __exit__(self, *exc) -> bool:
+        self._into.inc(self._clock() - self._t0)
+        return self._span.__exit__(*exc)
+
+
+class HostClock:
+    """Host wall time summed into a metric; the one sanctioned clock read.
+
+    ``with clock.host_span("repro.serve.plan", into=counter):`` opens the
+    same profiler span as :func:`host_span` and adds the block's elapsed
+    seconds to ``counter`` (anything with ``inc``).  The caller gets no
+    time back.  ``clock`` is injectable: tests pass a fake.
+    """
+
+    __slots__ = ("_clock",)
+
+    def __init__(self, clock=time.perf_counter) -> None:
+        self._clock = clock
+
+    def host_span(self, name: str, into, **args) -> _TimedSpan:
+        # lint: allow(event-trace-site): the seam forwards its caller's
+        # name, which the rule checks at that call
+        return _TimedSpan(self._clock, host_span(name, **args), into)
 
 
 class TraceRecorder:

@@ -25,6 +25,7 @@ import numpy as np
 from repro.core.stm import Transaction, VersionedStore
 from repro.dist.locality import HBM_BW
 from repro.obs.metrics import MetricSet
+from repro.obs.trace import host_span
 
 # fixed per-batch cost: kernel dispatch + result sync
 CERT_DISPATCH_S = 20e-6
@@ -162,6 +163,11 @@ class StepCertifier:
         aborted requests carried a stale lease epoch (the session was
         acquired away after routing) and must be re-routed by the caller.
         """
+        with host_span("repro.serve.certify", pod=pod,
+                       batch=len(self.pending[pod])):
+            return self._drain(pod)
+
+    def _drain(self, pod: int) -> Tuple[List, List, float]:
         entries = self.pending[pod]
         if not entries:
             self._flush_bumps()

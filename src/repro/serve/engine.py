@@ -35,8 +35,8 @@ import numpy as np
 
 from repro.dist.locality import DCN_RTT_S, price_session_dispatch
 from repro.launch.hlo_analysis import HBM_BW
-from repro.obs.metrics import MetricSet, MonotonicSampler
-from repro.obs.trace import TraceRecorder
+from repro.obs.metrics import Counter, MetricSet
+from repro.obs.trace import HostClock, TraceRecorder, host_span
 from .certifier import StepCertifier
 from .router import LocalityRouter, RouteDecision
 
@@ -92,6 +92,11 @@ class RealBackend:
     replica of the params live on that device, so the pods are one-chip
     replicas behind the router and a KV acquire crosses chips.  Without it
     every pod shares the default device and one params tree.
+
+    ``decode_s`` sums the host wall time of every :meth:`step`, input
+    build to the argmax back on the host, through ``host_clock`` (the
+    ``repro.serve.decode`` span); the engine puts each step's share into
+    its token-latency histograms.
     """
 
     def __init__(self, cfg, ctx, params, n_pods: int, n_slots: int,
@@ -129,6 +134,8 @@ class RealBackend:
             return decoder.decode_step(cfg, ctx, params, caches, tokens, pos)
 
         self._step = jax.jit(step)
+        self.host_clock = HostClock()
+        self.decode_s = Counter("decode_s", 0.0)
 
     def ensure(self, pod: int, sid: int, length: int) -> None:
         st = self.stores[pod]
@@ -155,21 +162,29 @@ class RealBackend:
 
     def step(self, pod: int, sids: List[int]) -> Dict[int, int]:
         """One batched decode for the pod's sessions; returns new tokens."""
-        jnp = self._jnp
-        st = self.stores[pod]
         if not sids:
             return {}
-        tokens = np.zeros((st.n_slots,), np.int32)
-        pos = np.zeros((st.n_slots,), np.int32)
-        for sid in sids:
-            s = st.sessions[sid]
-            tokens[s.slot] = s.last_token
-            pos[s.slot] = s.length
+        with self.host_clock.host_span("repro.serve.decode", self.decode_s,
+                                       pod=pod, batch=len(sids)):
+            return self._decode(pod, sids)
+
+    def _decode(self, pod: int, sids: List[int]) -> Dict[int, int]:
+        jnp = self._jnp
+        st = self.stores[pod]
         dev = self.devices[pod]
-        logits, st.caches = self._step(
-            self.pod_params[pod], st.caches, self._put(tokens, dev),
-            self._put(pos, dev))
-        nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+        with host_span("repro.serve.inputs"):
+            tokens = np.zeros((st.n_slots,), np.int32)
+            pos = np.zeros((st.n_slots,), np.int32)
+            for sid in sids:
+                s = st.sessions[sid]
+                tokens[s.slot] = s.last_token
+                pos[s.slot] = s.length
+            tokens, pos = self._put(tokens, dev), self._put(pos, dev)
+        with host_span("repro.serve.dispatch"):
+            logits, st.caches = self._step(
+                self.pod_params[pod], st.caches, tokens, pos)
+        with host_span("repro.readback"):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
         out = {}
         for sid in sids:
             s = st.sessions[sid]
@@ -205,8 +220,8 @@ class EngineMetrics(MetricSet):
         "plan_block_s": 0.0,     # host wall-time planning spent ON the token
         # path (begin dispatch + finish harvest; sync mode pays the full
         # scoring wait here, async mode only the dispatch + a drained
-        # harvest) — sampled through obs.metrics.MonotonicSampler, the one
-        # sanctioned wall-clock seam
+        # harvest) — summed by the repro.serve.plan span of
+        # obs.trace.HostClock, the one sanctioned wall-clock seam
     }
 
     def __init__(self, n_pods: int = 0, cert: Optional[object] = None,
@@ -232,7 +247,8 @@ class EngineMetrics(MetricSet):
                               n: int = 1) -> None:
         """Record ``n`` tokens decoded at ``pod`` whose step latency was
         ``lat_s`` (the pod's full busy time for that step: wire + certify
-        + decode — what a request experiences per token)."""
+        + decode — what a request experiences per token; under
+        ``RealBackend`` the decode is its measured host wall time)."""
         self.registry.histogram("token_lat_s").observe(lat_s, n)
         if 0 <= pod < self.n_pods:
             self.registry.histogram(f"pod{pod}.token_lat_s").observe(
@@ -297,7 +313,7 @@ class MultiPodEngine:
         self.trace = trace
         # the sanctioned wall-clock seam for plan_block_s (host scoring
         # time is genuinely wall time; everything else here is simulated)
-        self._mono = MonotonicSampler()
+        self.host_clock = HostClock()
         # forwarded requests are certified at the owning pod in one batch
         # per engine step (the paper's commit phase at the lease owner)
         self.certifier = certifier or StepCertifier(n_pods, sanitize=sanitize)
@@ -330,8 +346,13 @@ class MultiPodEngine:
         self._pod_clock = np.zeros((n_pods,), np.float64)
         self.metrics = EngineMetrics(n_pods=n_pods,
                                      cert=self.certifier.metrics)
+        self._plan_block = self.metrics.registry.counter("plan_block_s")
 
     def submit(self, req: Request) -> RouteDecision:
+        with host_span("repro.serve.route"):
+            return self._submit(req)
+
+    def _submit(self, req: Request) -> RouteDecision:
         m = self.metrics
         length = self.session_len.get(req.sid, 0)
         dec = self.router.route(req.origin, req.sid, length)
@@ -433,6 +454,10 @@ class MultiPodEngine:
 
     def run_step(self) -> None:
         """One decode step on every pod over its queued sessions."""
+        with host_span("repro.serve.step"):
+            self._run_step()
+
+    def _run_step(self) -> None:
         m = self.metrics
         # harvest the plan kicked at the previous step's epoch boundary:
         # its scoring ran on-device while that whole step decoded, so the
@@ -476,7 +501,14 @@ class MultiPodEngine:
                     t_dec = self.backend.decode_time_s(
                         pod, sids, self.router.kv_bytes_per_token)
                     pod_t += t_dec
+                # a measured backend (RealBackend.decode_s) adds its host
+                # wall time to the token latencies; the pod clocks and the
+                # router keep the deterministic priced time
+                measured = getattr(self.backend, "decode_s", None)
+                before = measured.value if measured is not None else 0.0
                 self.backend.step(pod, sids)
+                t_meas = measured.value - before if measured is not None \
+                    else 0.0
                 for r in reqs:
                     r.n_tokens -= 1
                 # the pod decodes each *session* once per step, however many
@@ -489,8 +521,8 @@ class MultiPodEngine:
                 n_dec = len(sids)
                 # per-token latency at this pod this step: the busy time a
                 # decoded token just experienced (wire + certify + decode)
-                if pod_t > 0:
-                    m.observe_token_latency(pod, pod_t, n_dec)
+                if pod_t + t_meas > 0:
+                    m.observe_token_latency(pod, pod_t + t_meas, n_dec)
                 self.queues[pod] = [r for r in reqs if r.n_tokens > 0]
             tr = self.trace
             if tr is not None:
@@ -537,9 +569,12 @@ class MultiPodEngine:
         """Snapshot the epoch's inputs and dispatch the [session, pod]
         scoring (one jit'd evaluation, mesh-sharded when the planner holds
         a plan mesh) without waiting on it."""
+        with self.host_clock.host_span("repro.serve.plan", self._plan_block):
+            return self._kick_plan_epoch()
+
+    def _kick_plan_epoch(self):
         from repro.plan.score import price_move_costs
 
-        self._mono.mark()
         r = self.router
         self.metrics.plan_epochs += 1
         n_cls = r.affinity.node.n_cols
@@ -554,7 +589,6 @@ class MultiPodEngine:
             state, work, seq_shards=r.seq_shards)
         pending = self.planner.begin(r._now, owner, state, fwd_cost,
                                      move_cost, r.cpu)
-        self.metrics.plan_block_s += self._mono.lap()
         tr = self.trace
         if tr is not None:
             # async epoch: opened at the kick, closed at the harvest — the
@@ -570,9 +604,8 @@ class MultiPodEngine:
         a session acquired away (or evicted) since the kick keeps its
         snapshot move from firing."""
         r = self.router
-        self._mono.mark()
-        plan = self.planner.finish(pending)
-        self.metrics.plan_block_s += self._mono.lap()
+        with self.host_clock.host_span("repro.serve.plan", self._plan_block):
+            plan = self.planner.finish(pending)
         executed = []
         for mv in plan.moves:
             if r.owner.get(mv.cc) == mv.src and mv.src != mv.dst:
@@ -636,6 +669,10 @@ class MultiPodEngine:
         of the sid places at an epoch above the tombstone (see
         ``LocalityRouter.evict``).
         """
+        with host_span("repro.serve.evict"):
+            self._evict(sid)
+
+    def _evict(self, sid: int) -> None:
         home = self.session_home.pop(sid, None)
         self.session_len.pop(sid, None)
         for pod in range(self.n_pods):

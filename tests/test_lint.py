@@ -276,6 +276,31 @@ def test_trace_site_quiet_on_literal_names_and_other_receivers():
     assert vs == []
 
 
+def test_trace_site_flags_computed_or_unprefixed_host_span_names():
+    _, vs = _rules("""
+        from repro.obs.trace import host_span
+
+        def f(self, name, node):
+            with host_span(f"repro.tm.{name}"):
+                pass
+            with host_span(name, node=node):
+                pass
+            with host_span("tm.certify"):
+                pass
+            with self.host_clock.host_span("decode", self.decode_s):
+                pass
+            with host_span():
+                pass
+            with host_span("repro.tm.certify", node=node, batch=len(name)):
+                pass
+            with self.host_clock.host_span("repro.serve.plan", self.acc):
+                pass
+    """, trace_site.RULE)
+    assert [v.rule for v in vs] == ["event-trace-site"] * 5
+    assert "f-string" in vs[0].msg and "'tm.certify'" in vs[2].msg
+    assert "no name" in vs[4].msg
+
+
 # ---------------------------------------------------------------------------
 # ops<->ref parity
 # ---------------------------------------------------------------------------

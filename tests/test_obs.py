@@ -1,15 +1,17 @@
-"""repro.obs tests: quantile metrics, trace schema, determinism, zero-cost."""
+"""repro.obs tests: quantile metrics, trace schema, determinism, zero-cost,
+and the host-clock spans on the profiler's clock."""
+import dataclasses
+import glob
 import json
-import math
 
+import jax
 import numpy as np
 import pytest
 
 from repro.core import BankWorkload, SimConfig, make_cluster
 from repro.obs import trace as obs_trace
-from repro.obs.metrics import (Counter, Gauge, Histogram, MetricSet,
-                               MonotonicSampler, Registry)
-from repro.obs.trace import NULL, TraceRecorder
+from repro.obs.metrics import Counter, Histogram, MetricSet, Registry
+from repro.obs.trace import NO_SPAN, NULL, HostClock, TraceRecorder, host_span
 
 
 # --------------------------------------------------------------------------
@@ -26,26 +28,6 @@ def test_histogram_quantiles_match_numpy():
         for q in (0.0, 0.5, 0.9, 0.99, 1.0):
             want = float(np.percentile(samples, 100.0 * q))
             assert h.quantile(q) == pytest.approx(want, rel=1e-12, abs=1e-12)
-
-
-def test_histogram_pow2_bucket_edges():
-    h = Histogram("b")
-    # exact powers of two land in their own bucket [2^k, 2^(k+1)),
-    # just-below values in the one underneath
-    for v in (1.0, 2.0, 4.0, 8.0):
-        h.observe(v)
-    h.observe(3.999999)
-    h.observe(0.0)
-    h.observe(-1.5)
-    assert h.buckets[0] == 1          # [1, 2)
-    assert h.buckets[1] == 2          # [2, 4): 2.0 and 3.999999
-    assert h.buckets[2] == 1          # [4, 8)
-    assert h.buckets[3] == 1          # [8, 16)
-    assert h.buckets["le_zero"] == 2  # 0.0 and -1.5
-    assert h.count == 7
-    # fractional values bucket by floor(log2): 0.3 -> k=-2
-    h.observe(0.3)
-    assert h.buckets[math.floor(math.log2(0.3))] == 1
 
 
 def test_histogram_slo_attainment_and_summary():
@@ -69,15 +51,12 @@ def test_registry_and_scalar_metrics():
     c.inc()
     c.inc(4)
     assert r.counter("steps").value == 5       # same object on re-access
-    g = r.gauge("depth")
-    g.set(3.5)
-    r.histogram("lat").observe(2.0)
+    r.histogram("lat").observe(2.0, n=3)
     d = r.as_dict()
-    assert d["steps"] == 5 and d["depth"] == 3.5
-    assert d["lat"]["count"] == 1
+    assert d["steps"] == 5
+    assert d["lat"]["count"] == 3
     assert "steps" in r and "missing" not in r
     assert isinstance(r.counter("steps"), Counter)
-    assert isinstance(r.gauge("depth"), Gauge)
 
 
 def test_metricset_facade_routes_to_registry():
@@ -99,14 +78,39 @@ def test_metricset_facade_routes_to_registry():
 
 
 def test_monotonic_sampler_with_fake_clock():
+    """The monotonic wall-clock seam, ``HostClock``, sums each span's time
+    into its counter and hands the caller none."""
     ticks = iter([10.0, 10.5, 11.0, 13.25])
-    s = MonotonicSampler(clock=lambda: next(ticks))
-    assert s.lap() == 0.0            # lap before mark is a no-op
-    s.mark()
-    assert s.lap() == pytest.approx(0.5)
-    assert s.lap() == 0.0            # interval consumed
-    s.mark()
-    assert s.lap() == pytest.approx(2.25)
+    hc = HostClock(clock=lambda: next(ticks))
+    into = Counter("plan_block_s", 0.0)
+    with hc.host_span("repro.serve.plan", into) as got:
+        assert got is None           # the span hands its caller no time
+    assert into.value == pytest.approx(0.5)
+    with hc.host_span("repro.serve.plan", into, batch=3):
+        pass
+    assert into.value == pytest.approx(2.75)
+    # an exception still closes the span and counts its time, and escapes
+    ticks = iter([0.0, 1.0])
+    hc = HostClock(clock=lambda: next(ticks))
+    with pytest.raises(KeyError):
+        with hc.host_span("repro.serve.plan", into):
+            raise KeyError("x")
+    assert into.value == pytest.approx(3.75)
+
+
+def test_host_span_off_is_the_shared_noop_and_builds_no_args():
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    calls = []
+
+    def nbytes():
+        calls.append(1)
+        return 8
+
+    span = host_span("repro.ops.validate", h2d_bytes=nbytes)
+    assert span is NO_SPAN and host_span("repro.tm.events") is NO_SPAN
+    with span as got:
+        assert got is None
+    assert calls == []               # the lazy arg was never evaluated
 
 
 # --------------------------------------------------------------------------
@@ -223,6 +227,75 @@ def test_seeded_traces_are_byte_identical(tmp_path):
              if e["ph"] != "M"}
     assert "exec" in names and "lease-round" in names
     assert "certify-batch" in names
+
+
+def _certify_cluster(seed: int = 3):
+    """A small bank cluster whose every certification and lease settle
+    takes the device path."""
+    cfg = SimConfig(duration_ms=25.0, warmup_ms=5.0, drain_ms=25.0,
+                    seed=seed, certify_jax_min=1, lease_jax_min=1)
+    wl = BankWorkload(n_nodes=cfg.n_nodes, n_items=cfg.n_items, locality=0.8)
+    return make_cluster("LILAC-TM-OPT", wl, cfg)
+
+
+def _profiled_run(trace_dir):
+    c = _certify_cluster()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        m = c.run()
+    finally:
+        jax.profiler.stop_trace()
+    return c, m
+
+
+def _host_spans(trace_dir):
+    """``(name, start_ns, end_ns)`` of every ``repro.`` span in the
+    profile, read back with ``ProfileData``."""
+    from jax.profiler import ProfileData
+
+    path = sorted(glob.glob(f"{trace_dir}/**/*.xplane.pb", recursive=True))
+    data = ProfileData.from_file(path[-1])
+    out = []
+    for plane in data.planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro."):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns))
+    return out
+
+
+def test_profiled_certify_nests_its_host_spans(tmp_path):
+    _profiled_run(tmp_path)
+    spans = _host_spans(tmp_path)
+    certs = [s for s in spans if s[0] == "repro.tm.certify"]
+    assert certs
+    inner = {"repro.tm.write_locks", "repro.stm.pack", "repro.ops.validate",
+             "repro.readback", "repro.tm.apply"}
+    for _, c0, c1 in certs:
+        inside = {n for n, s0, s1 in spans if c0 <= s0 and s1 <= c1}
+        assert inner <= inside, inside
+    # every certification drain runs inside the event loop's span
+    loops = [s for s in spans if s[0] == "repro.tm.events"]
+    assert all(any(l0 <= c0 and c1 <= l1 for _, l0, l1 in loops)
+               for _, c0, c1 in certs)
+    names = {n for n, _, _ in spans}
+    assert {"repro.tm.deliver", "repro.tm.execute", "repro.tm.settle",
+            "repro.ops.settle"} <= names
+
+
+def test_profiled_run_matches_unprofiled(tmp_path):
+    c_on, m_on = _profiled_run(tmp_path)
+    c_off = _certify_cluster()
+    m_off = c_off.run()
+    assert dataclasses.asdict(m_on) == dataclasses.asdict(m_off)
+    assert m_off.cert_batches > 0
+    for r_on, r_off in zip(c_on.replicas, c_off.replicas):
+        assert np.array_equal(r_on.store.values, r_off.store.values)
+        assert np.array_equal(r_on.store.versions, r_off.store.versions)
 
 
 def test_untraced_sim_allocates_no_recorder():

@@ -420,3 +420,34 @@ def test_engine_async_plan_epoch_kicks_then_harvests():
     eng_sync, saw_pending_sync = run(False)
     assert not saw_pending_sync              # sync epochs never leave a pending
     assert eng_sync.router.owner[5] == 1     # same steady state
+
+
+def test_real_backend_decode_wall_time_feeds_token_latency():
+    """Under RealBackend each step's measured decode time (the
+    ``repro.serve.decode`` span through the backend's HostClock) lands in
+    the token-latency histograms; the pod clocks and the router clock keep
+    the priced time."""
+    import itertools
+
+    from repro.obs.trace import HostClock
+    from repro.serve.engine import REAL_STEP_MS
+
+    params = init_params(CFG, jax.random.PRNGKey(0))
+    backend = RealBackend(CFG, CTX, params, n_pods=1, n_slots=4, max_len=32)
+    ticks = itertools.count(0.0, 0.25)   # each read 0.25 s after the last
+    backend.host_clock = HostClock(clock=lambda: next(ticks))
+    router = LocalityRouter(1, policy="short")
+    eng = MultiPodEngine(1, backend, router)
+    for sid in range(3):
+        eng.submit(Request(sid=sid, origin=0, n_tokens=2))
+    now0 = router._now
+    eng.run_step()
+    eng.run_step()
+    fleet = eng.metrics.token_latency()
+    assert fleet.count == 6
+    assert fleet.samples == pytest.approx([0.25] * 6)
+    assert eng.metrics.token_latency(0).count == 6
+    assert backend.decode_s.value == pytest.approx(0.5)
+    assert eng.metrics.as_dict()["token_lat_p99_s"] == pytest.approx(0.25)
+    assert eng.metrics.sim_time_s == 0.0
+    assert router._now - now0 == pytest.approx(2 * REAL_STEP_MS)
