@@ -313,38 +313,51 @@ class LeaseSanitizer:
 def check_write_locks(node: int, owners: np.ndarray,
                       item_cc: Optional[np.ndarray],
                       locks: Optional[np.ndarray],
-                      txns: Sequence, verdicts: Sequence) -> int:
+                      txns: Sequence, verdicts: Sequence,
+                      domain: str = "item") -> int:
     """Single-writer check on one certification batch (simulator side).
 
-    Recomputes per-item write locks from the lease layer's *current*
-    ownership view — independently of the production derivation — and
-    flags (a) a stale/forged ``locks`` input to ``validate_batch``, and
-    (b) any passing transaction that writes an item leased elsewhere.
-    Returns the number of write slots checked.
+    Recomputes the write locks from the lease layer's *current* ownership
+    view — independently of the production derivation — and flags (a) a
+    stale/forged ``locks`` input to ``validate_batch``, and (b) any passing
+    transaction that writes an item leased elsewhere.  ``domain`` says what
+    ``locks`` holds: one lock an item (``"item"``) or one a conflict class
+    (``"class"``, looked up through ``item_cc``).  Returns the number of
+    write slots checked.
     """
     if item_cc is None:
         return 0
-    per_item = np.asarray(owners)[np.asarray(item_cc)]
-    expected = (per_item >= 0) & (per_item != node)
+    if domain not in ("item", "class"):
+        raise ValueError(f"unknown write-lock domain {domain!r}")
+    owners = np.asarray(owners)
+    item_cc = np.asarray(item_cc)
+    leased_away = (owners >= 0) & (owners != node)
     if locks is not None:
+        expected = leased_away if domain == "class" else leased_away[item_cc]
         got = np.asarray(locks).astype(bool)
+        if got.shape != expected.shape:
+            raise SanitizerError(
+                "write-locks",
+                f"stale write-lock input at node {node}: {got.size} locks "
+                f"for {expected.size} {domain}(s)")
         if not np.array_equal(got, expected):
             bad = np.flatnonzero(got != expected)
             raise SanitizerError(
                 "write-locks",
                 f"stale write-lock input at node {node}: {bad.size} "
-                f"item(s) diverge from the lease ownership view, e.g. "
-                f"item {int(bad[0])}")
+                f"{domain}(s) diverge from the lease ownership view, e.g. "
+                f"{domain} {int(bad[0])}")
     n = 0
     for t, ok in zip(txns, verdicts):
         if not ok:
             continue
         for item in t.write_set:
             n += 1
-            if expected[item]:
+            cc = int(item_cc[item])
+            if leased_away[cc]:
                 raise SanitizerError(
                     "write-locks",
                     f"txn {t.txid} passed certification at node {node} "
                     f"while writing item {item} leased to proc "
-                    f"{int(per_item[item])}")
+                    f"{int(owners[cc])}")
     return n

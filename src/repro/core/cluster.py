@@ -304,8 +304,8 @@ class Cluster:
         self._reqid = itertools.count(1)
         self._stopped = False
         self._inflight: Dict[int, SimTxn] = {}
-        # item -> conflict class, used to derive per-item write-lock state
-        # from the lease layer for the certification kernel
+        # item -> conflict class: certification looks up each written item's
+        # class lock (``_write_locks``) through it
         if hasattr(self.ccmap, "of_item"):
             self._item_cc = np.fromiter(
                 (self.ccmap.of_item(i) for i in range(cfg.n_items)),
@@ -792,20 +792,20 @@ class Cluster:
                 self.cfg.certify_window_ms, lambda: self._drain_certify(node))
 
     def _write_locks(self, node: int) -> Optional[np.ndarray]:
-        """Per-item write-lock state from the lease layer's ownership view.
+        """Per-class write-lock state from the lease layer's ownership view.
 
-        An item is write-locked at ``node`` when its conflict class is
-        currently leased to a *different* replica.  Enabled transactions head
-        every queue they touch, so a lock conflict here means the batch was
-        fed a transaction the lease layer never enabled — the kernel turns
-        that protocol violation into an abort instead of a silent pass.
+        A conflict class is write-locked at ``node`` when it is currently
+        leased to a *different* replica; an item is locked when its class
+        (``self._item_cc``) is.  Enabled transactions head every queue they
+        touch, so a lock conflict here means the batch was fed a transaction
+        the lease layer never enabled — the kernel turns that protocol
+        violation into an abort instead of a silent pass.
         """
         if self._item_cc is None:
             return None
         with host_span("repro.tm.write_locks"):
             owners = self.replicas[node].lm.owner_np()
-            per_item = owners[self._item_cc]
-            return ((per_item >= 0) & (per_item != node)).astype(np.int32)
+            return ((owners >= 0) & (owners != node)).astype(np.int32)
 
     def _locked_write(self, txn: SimTxn, node: int) -> bool:
         """Per-txn twin of the kernels' lock check (small-batch path)."""
@@ -832,7 +832,8 @@ class Cluster:
         r = self.replicas[node]
         locks = self._write_locks(node)
         if len(batch) >= self.cfg.certify_jax_min:
-            ok = validate_batch(r.store, [t.stm for t in batch], locks=locks)
+            ok = validate_batch(r.store, [t.stm for t in batch], locks=locks,
+                                lock_of_item=self._item_cc)
         else:
             # near-empty batch: JAX dispatch overhead would dominate — the
             # numpy loop settles the same verdicts, including the lock
@@ -848,7 +849,8 @@ class Cluster:
 
             check_write_locks(
                 node, r.lm.owner_np(), self._item_cc, locks,
-                [t.stm for t in batch], [bool(o) for o in ok])
+                [t.stm for t in batch], [bool(o) for o in ok],
+                domain="class")
         self.metrics.cert_batches += 1
         self.metrics.cert_batch_txns += len(batch)
         # Intra-batch serialization: the one-at-a-time path applies each

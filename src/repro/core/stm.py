@@ -290,6 +290,7 @@ def pack_write_sets(
 
 def validate_batch(store: VersionedStore, txns: Sequence[Transaction],
                    locks: np.ndarray | None = None,
+                   lock_of_item: np.ndarray | None = None,
                    backend: str = "auto") -> np.ndarray:
     """Batched TL2 certification of ``txns`` against ``store``.
 
@@ -297,9 +298,12 @@ def validate_batch(store: VersionedStore, txns: Sequence[Transaction],
     :func:`repro.kernels.ops.validate_transactions` — the Pallas kernel on
     TPU, the jit'd jnp oracle elsewhere; tests assert the two agree bitwise.
 
-    ``locks`` is an optional [n_items] 0/1 array of write locks (item leased
-    away per the lease layer): a transaction writing a locked item fails
-    certification on both backends.
+    ``locks`` is an optional 0/1 array of write locks: a transaction writing
+    a locked item fails certification on both backends.  It is per item
+    (``[n_items]``) when ``lock_of_item`` is None, else indexed by
+    ``lock_of_item[item]`` (e.g. one lock a conflict class).  Either way the
+    kernel gets one lock bit per write entry, gathered here, so its shapes
+    depend only on the batch's packed widths, not on the locks' domain.
     """
     if not txns:
         return np.zeros((0,), dtype=bool)
@@ -321,14 +325,33 @@ def validate_batch(store: VersionedStore, txns: Sequence[Transaction],
             if witems is not None:
                 witems = np.pad(witems, ((0, bp - b), (0, 0)),
                                 constant_values=-1)
+        wbits = None
+        if witems is not None:
+            wbits, witems = _entry_locks(locks, lock_of_item, witems)
         versions = store.versions.astype(np.int32)  # beats a device cast
     out = validate_transactions(
         versions,
         items,
         vers,
-        write_locks=locks,
+        write_locks=wbits,
         write_items=witems,
         backend=backend,
     )
     with host_span("repro.readback"):
         return np.asarray(out[:b])
+
+
+def _entry_locks(locks: np.ndarray, lock_of_item: np.ndarray | None,
+                 witems: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """One lock bit per write entry of the packed ``[B, W]`` ``witems``.
+
+    Returns the flat ``[B * W]`` bits and the ``[B, W]`` entry indices into
+    them (-1 where ``witems`` is -1, whose bit is 0).
+    """
+    valid = witems >= 0
+    at = np.where(valid, witems, 0)
+    if lock_of_item is not None:
+        at = np.asarray(lock_of_item)[at]
+    bits = np.where(valid, np.asarray(locks)[at], 0).astype(np.int32)
+    entry = np.arange(witems.size, dtype=np.int32).reshape(witems.shape)
+    return bits.reshape(-1), np.where(valid, entry, -1)

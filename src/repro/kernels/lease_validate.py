@@ -16,9 +16,11 @@ every entry's item against the chunk's lane ids; an entry is bad when the
 lane it hits holds a value other than the one it expects.  The chunk axis
 is the innermost grid dim, so the per-entry flag accumulates in the
 resident output block across the sweep.  Reads expect their snapshot
-version; writes run through the same kernel against the lock array
-expecting 0 (unlocked).  Per-transaction verdicts are the row-wise OR
-outside the kernel.
+version; writes run through the same kernel against the lock values they
+index expecting 0 (unlocked).  The lock values need not be per item: the
+certifier hands one bit per write entry, so the lock sweep is as long as
+the batch's write entries, not the store.  Per-transaction verdicts are
+the row-wise OR outside the kernel.
 """
 from __future__ import annotations
 
@@ -85,8 +87,8 @@ def lease_validate(
     store_versions: jax.Array,    # [n_items] int32
     read_items: jax.Array,        # [B, R] int32 (-1 padded)
     read_versions: jax.Array,     # [B, R] int32
-    write_locks: jax.Array,       # [n_items] int32 (0/1)
-    write_items: jax.Array,       # [B, W] int32 (-1 padded)
+    write_locks: jax.Array,       # [L] int32 (0/1), indexed by write_items
+    write_items: jax.Array,       # [B, W] int32 into write_locks (-1 padded)
     *,
     block_entries: int = 256,
     chunk: int = 1024,

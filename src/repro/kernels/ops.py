@@ -104,16 +104,19 @@ def validate_transactions(
 ):
     """Batched TL2 certification — the single dispatch point both the
     simulator (``repro.core.stm.validate_batch``) and the serving certifier
-    (``repro.serve.certifier``) go through.  Write locks default to none
-    (all zeros); both backends honor them identically.
+    (``repro.serve.certifier``) go through.  ``write_items`` index
+    ``write_locks`` (any length: one value an item, or one a write entry);
+    without locks every write passes, on both backends alike.
     """
     with host_span("repro.ops.validate", h2d_bytes=lambda: _h2d_bytes(
             store_versions, read_items, read_versions, write_locks,
-            write_items)):
+            write_items), lock_lanes=lambda: (
+                1 if write_locks is None else len(write_locks))):
         b = read_items.shape[0]
         store_versions = jnp.asarray(store_versions, jnp.int32)
         if write_locks is None:
-            write_locks = jnp.zeros_like(store_versions)
+            # one unlocked lane: nothing to sweep per item
+            write_locks = jnp.zeros((1,), jnp.int32)
         else:
             write_locks = jnp.asarray(write_locks, jnp.int32)
         if write_items is None:

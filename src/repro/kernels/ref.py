@@ -101,16 +101,20 @@ def lease_validate_ref(
     store_versions: jax.Array,   # [n_items] int32
     read_items: jax.Array,       # [B, R] int32, -1 padded
     read_versions: jax.Array,    # [B, R] int32
-    write_locks: Optional[jax.Array] = None,   # [n_items] bool
+    write_locks: Optional[jax.Array] = None,   # [L] bool
     write_items: Optional[jax.Array] = None,   # [B, W] int32, -1 padded
 ) -> jax.Array:
-    """TL2 certification: read versions unchanged AND write set unlocked."""
+    """TL2 certification: read versions unchanged AND write set unlocked.
+
+    ``write_items`` index ``write_locks``, which need not be as long as the
+    store: the certifier hands one lock bit per write entry."""
     n = store_versions.shape[0]
     valid = read_items >= 0
     cur = store_versions[jnp.clip(read_items, 0, n - 1)]
     ok = jnp.all(jnp.where(valid, cur == read_versions, True), axis=1)
     if write_locks is not None and write_items is not None:
         wvalid = write_items >= 0
-        locked = write_locks[jnp.clip(write_items, 0, n - 1)]
+        nl = write_locks.shape[0]
+        locked = write_locks[jnp.clip(write_items, 0, nl - 1)]
         ok &= jnp.all(jnp.where(wvalid, ~locked, True), axis=1)
     return ok

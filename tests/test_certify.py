@@ -167,17 +167,174 @@ def test_backends_agree_bitwise_with_locks_and_writes():
 
 
 def test_cluster_write_locks_reflect_lease_ownership():
-    """_write_locks marks exactly the items whose conflict class is leased
-    to another replica."""
+    """_write_locks marks exactly the conflict classes leased to another
+    replica; expanded through the item -> class map it is the per-item
+    derivation, item for item."""
     c, _ = _run_mode("batched", locality=0.3, seed=7)
     for node in range(c.cfg.n_nodes):
         locks = c._write_locks(node)
         lm = c.replicas[node].lm
+        assert locks.shape == (c.cfg.n_classes,) and locks.dtype == np.int32
+        per_item = lm.owner_np()[c._item_cc]
+        np.testing.assert_array_equal(
+            locks[c._item_cc], (per_item >= 0) & (per_item != node))
         items = np.random.default_rng(0).integers(0, c.cfg.n_items, 200)
         for it in items:
             cc = c.ccmap.of_item(int(it))
             owner = lm.head_owner(cc)
-            assert bool(locks[it]) == (owner >= 0 and owner != node)
+            assert bool(locks[c._item_cc[it]]) == \
+                (owner >= 0 and owner != node)
+
+
+def _random_batch(rng, n_items, n_txns, max_reads=9, max_writes=9):
+    """Transactions with stale reads, repeated items and some rows with no
+    writes (or no reads) at all; the first reads fresh and writes nothing,
+    so it passes."""
+    store = VersionedStore(n_items)
+    store.versions[:] = rng.integers(0, 30, n_items)
+    txns = []
+    for i in range(n_txns):
+        t = Transaction(txid=i + 1, origin=0)
+        for it in rng.integers(0, n_items, rng.integers(0, max_reads)):
+            ver = int(store.versions[it])
+            if i and rng.random() < 0.1:
+                ver += 1                      # stale
+            t.log_read(int(it), ver)
+        for it in rng.integers(0, n_items,
+                               rng.integers(0, max_writes) if i else 0):
+            t.write_set[int(it)] = float(it)
+        txns.append(t)
+    return store, txns
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+@pytest.mark.parametrize("n_items,n_classes,n_txns,seed", [
+    (64, 5, 16, 1),       # 16 rows x 8 write lanes: more entries than items
+    (64, 3, 11, 2),       # 11 rows padded to 16
+    (500, 33, 60, 3),
+    (40, 1, 3, 4),        # one class, padded rows, tiny store
+])
+def test_class_domain_locks_match_item_domain(backend, n_items, n_classes,
+                                              n_txns, seed):
+    """Class-domain locks looked up through ``lock_of_item`` certify the
+    same verdicts as the item-domain ``locks_cls[lock_of_item]`` and as the
+    python loop, bit for bit."""
+    rng = np.random.default_rng(seed)
+    store, txns = _random_batch(rng, n_items, n_txns)
+    lock_of_item = rng.integers(0, n_classes, n_items).astype(np.int32)
+    locks_cls = (rng.random(n_classes) < 0.3).astype(np.int32)
+    locks_cls[0] = 1
+    locks_item = locks_cls[lock_of_item]
+    by_class = validate_batch(store, txns, locks=locks_cls,
+                              lock_of_item=lock_of_item, backend=backend)
+    by_item = validate_batch(store, txns, locks=locks_item, backend=backend)
+    loop = np.asarray([
+        store.validate(t) and not any(locks_item[it] for it in t.write_set)
+        for t in txns])
+    assert by_class.dtype == bool and by_class.shape == (n_txns,)
+    np.testing.assert_array_equal(by_class, loop)
+    np.testing.assert_array_equal(by_item, loop)
+    # the draw reaches both verdicts
+    assert loop.any() and not loop.all()
+
+
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_lock_values_longer_than_the_store(backend):
+    """``write_items`` index the lock values, not the store: on a store of
+    64 items, entry 100 of a 128-entry lock vector decides the verdict."""
+    from repro.kernels.ops import validate_transactions
+
+    store = np.zeros((64,), np.int32)
+    items = np.full((2, 8), -1, np.int32)
+    vers = np.zeros((2, 8), np.int32)
+    locks = np.zeros((128,), np.int32)
+    locks[100] = 1
+    witems = np.full((2, 8), -1, np.int32)
+    witems[0, 0], witems[1, 0] = 100, 63
+    out = validate_transactions(store, items, vers, write_locks=locks,
+                                write_items=witems, backend=backend)
+    np.testing.assert_array_equal(np.asarray(out), [False, True])
+
+
+# the footprints the TPC-C mix certifies, as its warm-up compiles them:
+# (reads, writes) for Payment, New-Order and its repeated-stock variants
+_TPCC_FOOTPRINTS = ((3, 3), (11, 6), (17, 6), (17, 9))
+
+
+def _footprint_batch(store, n, reads, writes):
+    txns = []
+    for i in range(n):
+        t = Transaction(txid=i + 1, origin=0)
+        for j in range(reads):
+            store.read(t, j)
+        for j in range(writes):
+            store.write(t, j, 0.0)
+        txns.append(t)
+    return txns
+
+
+def test_class_domain_locks_compile_no_new_shape():
+    """Item-domain locks warm the certify shapes; class-domain locks on the
+    same rows, reads and writes then hit them: the jit caches behind
+    ``validate_transactions`` gain no entry."""
+    from repro.kernels import ops
+
+    n_items, n_classes = 4096, 33
+    store = VersionedStore(n_items)
+    item_locks = np.zeros((n_items,), np.int32)
+    rows = (1, 5, 8, 9, 16)
+    for n in rows:
+        for reads, writes in _TPCC_FOOTPRINTS:
+            validate_batch(store, _footprint_batch(store, n, reads, writes),
+                           locks=item_locks)
+    warmed = ops._lease_validate_ref_jit._cache_size()
+    assert warmed > 0
+    lock_of_item = (np.arange(n_items) % n_classes).astype(np.int32)
+    cls_locks = np.zeros((n_classes,), np.int32)
+    cls_locks[1] = 1
+    for n in rows:
+        for reads, writes in _TPCC_FOOTPRINTS:
+            ok = validate_batch(store, _footprint_batch(store, n, reads,
+                                                        writes),
+                                locks=cls_locks, lock_of_item=lock_of_item)
+            assert not ok.any()               # every batch writes item 1
+    assert ops._lease_validate_ref_jit._cache_size() == warmed
+
+
+def test_certify_uploads_entries_not_a_lock_array(monkeypatch):
+    """A small cluster's certify calls upload the store's versions and the
+    packed entries, and sweep one lock lane a write entry: no second
+    ``[n_items]`` array."""
+    from repro.kernels import ops
+    from repro.obs.trace import NO_SPAN
+
+    spans, calls = [], []
+
+    def recording_span(name, **args):
+        if name == "repro.ops.validate":
+            spans.append({k: v() if callable(v) else v
+                          for k, v in args.items()})
+        return NO_SPAN
+
+    real = ops.validate_transactions
+
+    def shapes(store_versions, read_items, read_versions, write_locks=None,
+               write_items=None, **kw):
+        calls.append((read_items.shape, write_items.shape))
+        return real(store_versions, read_items, read_versions,
+                    write_locks=write_locks, write_items=write_items, **kw)
+
+    monkeypatch.setattr(ops, "host_span", recording_span)
+    monkeypatch.setattr(ops, "validate_transactions", shapes)
+    c, m = _run_mode("batched", locality=0.3, seed=7, certify_jax_min=1)
+    assert m.cert_batches > 0 and len(spans) == len(calls) > 0
+    n_items = c.cfg.n_items
+    for span, ((bp, r), (bw, w)) in zip(spans, calls):
+        assert bw == bp
+        entries = 4 * (2 * bp * r + 2 * bp * w)
+        assert span["h2d_bytes"] <= 4 * n_items + entries
+        assert span["h2d_bytes"] < 2 * 4 * n_items
+        assert span["lock_lanes"] == bp * w
 
 
 # ---------------------------------------------------------------------------

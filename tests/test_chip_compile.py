@@ -57,6 +57,18 @@ def test_lease_validate_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in c.as_text()
 
 
+def test_lease_validate_compiles_for_v5e_per_entry_locks(one_chip):
+    """The TPC-C cell's widest certify call: 1,140,088 store versions, 16
+    rows of 32 reads and 16 writes, and one lock bit a write entry."""
+    from repro.kernels.lease_validate import lease_validate
+
+    i32 = jnp.int32
+    n, b, r, w = 1140088, 16, 32, 16
+    c = _compile(lease_validate, *(_spec(one_chip, s, i32) for s in (
+        (n,), (b, r), (b, r), (b * w,), (b, w))))
+    assert "tpu_custom_call" in c.as_text()
+
+
 def test_flash_attention_compiles_for_v5e(one_chip):
     from repro.kernels.flash_attention import flash_attention
 

@@ -115,6 +115,36 @@ def test_mutant_certified_write_to_leased_away_item():
     assert "txn 7" in e.value.detail
 
 
+def test_mutant_stale_class_write_locks_input():
+    owners = np.array([0, 1], np.int32)         # cc=1 leased to proc 1
+    item_cc = np.array([0, 1, 1], np.int32)
+    stale = np.zeros(2, np.int32)               # the bug: locks not refreshed
+    with pytest.raises(SanitizerError) as e:
+        check_write_locks(0, owners, item_cc, stale, [], [], domain="class")
+    assert e.value.invariant == "write-locks"
+    assert "stale" in e.value.detail and "class" in e.value.detail
+    # item-domain locks handed over as class locks are caught too
+    with pytest.raises(SanitizerError) as e:
+        check_write_locks(0, owners, item_cc, np.array([0, 1, 1], np.int32),
+                          [], [], domain="class")
+    assert e.value.invariant == "write-locks"
+    # the fresh class view passes
+    assert check_write_locks(0, owners, item_cc, np.array([0, 1], np.int32),
+                             [_T(3, [0])], [True], domain="class") == 1
+
+
+def test_mutant_certified_class_locked_write_to_leased_away_item():
+    owners = np.array([0, 1], np.int32)
+    item_cc = np.array([0, 1, 1], np.int32)
+    locks = np.array([0, 1], np.int32)          # correct class locks
+    with pytest.raises(SanitizerError) as e:
+        # the bug: verdict True for a txn writing item 2 (leased to proc 1)
+        check_write_locks(0, owners, item_cc, locks,
+                          [_T(7, [0, 2])], [True], domain="class")
+    assert e.value.invariant == "write-locks"
+    assert "txn 7" in e.value.detail and "proc 1" in e.value.detail
+
+
 # -- mutant 6: recycled sid resurrects an old epoch --------------------------
 
 def test_mutant_recycled_sid_resurrection():
