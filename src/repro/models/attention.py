@@ -21,7 +21,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from .common import ModelConfig, apply_rope, rms_norm
+from .common import ModelConfig, apply_rope, rms_norm, yarn_mscale
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -253,6 +253,17 @@ def gqa_attention(
 # decoupled rope key k_pe [B, S, rope_dim] — 576 values/token/layer — which is
 # the paper-exact memory saving that makes 500k-token decode shardable.
 
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """``(qk_nope + qk_rope) ** -0.5``, times YaRN's ``mscale_all_dim``
+    correction squared when the rotary is scaled."""
+    m = cfg.mla
+    scale = (m.qk_nope_head_dim + m.qk_rope_head_dim) ** -0.5
+    y = cfg.rope_scaling
+    if y is not None and y.mscale_all_dim:
+        scale *= yarn_mscale(y.factor, y.mscale_all_dim) ** 2
+    return scale
+
+
 def mla_attention(
     p: Dict[str, jax.Array],
     x: jax.Array,
@@ -266,21 +277,31 @@ def mla_attention(
     is_global: bool = True,
     ctx=None,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    with jax.named_scope("repro.mla"):
+        return _mla(p, x, cfg, positions, cache=cache,
+                    cache_index=cache_index, return_cache=return_cache,
+                    use_kernel=use_kernel, ctx=ctx)
+
+
+def _mla(p, x, cfg, positions, *, cache, cache_index, return_cache,
+         use_kernel, ctx):
     m = cfg.mla
     b, s, _ = x.shape
     h = cfg.n_heads
     qk_dim = m.qk_nope_head_dim + m.qk_rope_head_dim
+    rope = functools.partial(apply_rope, theta=cfg.rope_theta,
+                             scaling=cfg.rope_scaling)
 
     # --- queries (low-rank) -------------------------------------------------
     cq = rms_norm(x @ p["wq_a"], p["q_norm"], cfg.norm_eps)
     q = (cq @ p["wq_b"]).reshape(b, s, h, qk_dim)
     q_nope, q_pe = q[..., : m.qk_nope_head_dim], q[..., m.qk_nope_head_dim:]
-    q_pe = apply_rope(q_pe, positions, cfg.rope_theta)
+    q_pe = rope(q_pe, positions)
 
     # --- compressed KV latent ------------------------------------------------
     ckv_full = x @ p["wkv_a"]                              # [B,S,kv_lora+rope]
     c_kv = rms_norm(ckv_full[..., : m.kv_lora_rank], p["kv_norm"], cfg.norm_eps)
-    k_pe = apply_rope(ckv_full[..., None, m.kv_lora_rank:], positions, cfg.rope_theta)
+    k_pe = rope(ckv_full[..., None, m.kv_lora_rank:], positions)
     k_pe = k_pe[..., 0, :]                                 # [B,S,rope_dim]
 
     q_pos = positions[0] if positions.ndim == 3 else positions
@@ -309,21 +330,30 @@ def mla_attention(
     wkv_b = p["wkv_b"].reshape(m.kv_lora_rank, h, m.qk_nope_head_dim + m.v_head_dim)
     w_k = wkv_b[..., : m.qk_nope_head_dim]                 # [r, h, dk]
     w_v = wkv_b[..., m.qk_nope_head_dim:]                  # [r, h, dv]
-    scale = qk_dim ** -0.5
+    scale = mla_softmax_scale(cfg)
     if s == 1 and cache is not None:
         # decode: absorb w_k into the query -> score directly in latent space,
         # never materializing [B, Skv, h, dk].  FLOPs/token: h*(dk*r + r) per
-        # key instead of expanding the whole cache.
-        q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope.astype(jnp.float32), w_k.astype(jnp.float32))
-        logits = jnp.einsum("bqhr,bkr->bhqk", q_lat, c_kv_use.astype(jnp.float32))
-        logits += jnp.einsum("bqhd,bkd->bhqk", q_pe.astype(jnp.float32),
-                             k_pe_use.astype(jnp.float32))
+        # key instead of expanding the whole cache.  Operands stay in the
+        # cache's type, products accumulate in float32: the cache is read
+        # as it is stored, never copied wider
+        cdt = c_kv_use.dtype
+        f32 = jnp.float32
+        q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope.astype(cdt), w_k.astype(cdt),
+                           preferred_element_type=f32).astype(cdt)
+        logits = jnp.einsum("bqhr,bkr->bhqk", q_lat, c_kv_use,
+                            preferred_element_type=f32)
+        logits += jnp.einsum("bqhd,bkd->bhqk", q_pe.astype(cdt), k_pe_use,
+                             preferred_element_type=f32)
         logits *= scale
         mask = attn_mask(q_pos, kv_pos, cfg.causal, None)
         logits = jnp.where(mask[:, None, :, :], logits, NEG_INF)
-        pr = jax.nn.softmax(logits, axis=-1)
-        ctx_lat = jnp.einsum("bhqk,bkr->bqhr", pr, c_kv_use.astype(jnp.float32))
-        out = jnp.einsum("bqhr,rhd->bqhd", ctx_lat, w_v.astype(jnp.float32)).astype(x.dtype)
+        pr = jax.nn.softmax(logits, axis=-1).astype(cdt)
+        ctx_lat = jnp.einsum("bhqk,bkr->bhqr", pr, c_kv_use,
+                             preferred_element_type=f32)
+        ctx_lat = ctx_lat.transpose(0, 2, 1, 3).astype(cdt)  # [B,1,h,r]
+        out = jnp.einsum("bqhr,rhd->bqhd", ctx_lat, w_v.astype(cdt),
+                         preferred_element_type=f32).astype(x.dtype)
     else:
         k_nope = jnp.einsum("bkr,rhd->bkhd", c_kv_use, w_k.astype(c_kv_use.dtype))
         v_full = jnp.einsum("bkr,rhd->bkhd", c_kv_use, w_v.astype(c_kv_use.dtype))

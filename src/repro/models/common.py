@@ -43,7 +43,7 @@ class MLAConfig:
 
 @dataclass(frozen=True)
 class MoEConfig:
-    n_experts: int = 8
+    n_experts: int = 8             # routed experts the router scores
     top_k: int = 2
     d_expert: int = 0              # expert FFN hidden dim
     n_shared: int = 0              # always-on shared experts (deepseek-v2)
@@ -51,6 +51,34 @@ class MoEConfig:
     first_dense_layers: int = 0    # leading layers that use a dense FFN
     d_first_dense: int = 0
     router_scale: float = 1.0      # routed-expert weight scale
+    # group-limited routing (deepseek-v2 ``group_limited_greedy``): the
+    # experts form ``n_group`` contiguous groups, a token keeps the
+    # ``topk_group`` groups with the highest best score and picks its
+    # ``top_k`` among their experts; 1 group is plain top-k
+    n_group: int = 1
+    topk_group: int = 1
+    # the expert share this layer holds: experts ``held_first ..
+    # held_first + n_held - 1`` (0 = all of them); the router keeps its
+    # ``n_experts`` outputs and absent experts contribute nothing
+    held_first: int = 0
+    n_held: int = 0
+
+    @property
+    def held(self) -> Tuple[int, int]:
+        """``(first, count)`` of the experts this layer holds."""
+        return self.held_first, self.n_held or self.n_experts
+
+
+@dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rotary scaling as DeepSeek-V2's ``rope_scaling`` gives it."""
+
+    factor: float = 40.0
+    original_max_position: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
@@ -93,6 +121,7 @@ class ModelConfig:
     rope_theta: float = 1e4
     rope_theta_global: Optional[float] = None    # gemma3 global layers
     partial_rotary: float = 1.0
+    rope_scaling: Optional[YarnScaling] = None   # deepseek-v2 (MLA only)
     mrope_sections: Optional[Tuple[int, ...]] = None    # qwen2-vl
     # attention pattern
     causal: bool = True            # False => bidirectional encoder
@@ -250,8 +279,35 @@ def mlp_shapes(d_model: int, d_ff: int, act: str) -> Dict[str, Tuple[int, ...]]:
 # Rotary embeddings (standard / partial / M-RoPE / dual-theta)
 # ---------------------------------------------------------------------------
 
-def rope_freqs(dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude correction ``0.1 m ln(factor) + 1`` (1 unscaled)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_freqs(dim: int, theta: float,
+               scaling: Optional[YarnScaling] = None) -> jax.Array:
+    """Inverse frequencies of the ``dim // 2`` rotated pairs.  With YaRN
+    scaling, each frequency is blended between the interpolated one (÷
+    ``factor``) and the original by a linear ramp over the correction
+    range that ``beta_fast`` / ``beta_slow`` rotations give at the
+    original context length."""
+    inv = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if scaling is None:
+        return inv
+
+    def correction_dim(rotations: float) -> float:
+        return (dim * math.log(scaling.original_max_position
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(scaling.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(scaling.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    extrapolate = 1.0 - ramp
+    return inv / scaling.factor * ramp + inv * extrapolate
 
 
 def apply_rope(
@@ -260,6 +316,7 @@ def apply_rope(
     theta: float,
     partial: float = 1.0,
     mrope_sections: Optional[Tuple[int, ...]] = None,
+    scaling: Optional[YarnScaling] = None,
 ) -> jax.Array:
     d = x.shape[-1]
     rot = int(d * partial)
@@ -267,7 +324,7 @@ def apply_rope(
     if rot == 0:
         return x
     xr, xp = x[..., :rot], x[..., rot:]
-    inv = rope_freqs(rot, theta)                         # [rot/2]
+    inv = rope_freqs(rot, theta, scaling)                # [rot/2]
     if mrope_sections is not None:
         # M-RoPE: frequency bands are split into sections, each rotated by a
         # different positional stream (temporal / height / width).  Text-only
@@ -286,8 +343,12 @@ def apply_rope(
         if positions.ndim == 3:
             positions = positions[0]
         ang = positions[..., None].astype(jnp.float32) * inv[None, None, :]
-    cos = jnp.cos(ang)[:, :, None, :].astype(x.dtype)    # [B, S, 1, rot/2]
-    sin = jnp.sin(ang)[:, :, None, :].astype(x.dtype)
+    amp = 1.0 if scaling is None else (
+        yarn_mscale(scaling.factor, scaling.mscale)
+        / yarn_mscale(scaling.factor, scaling.mscale_all_dim))
+    scale = (lambda t: t) if amp == 1.0 else (lambda t: t * amp)
+    cos = scale(jnp.cos(ang))[:, :, None, :].astype(x.dtype)  # [B, S, 1, rot/2]
+    sin = scale(jnp.sin(ang))[:, :, None, :].astype(x.dtype)
     x1, x2 = xr[..., : rot // 2], xr[..., rot // 2:]
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return jnp.concatenate([out, xp], axis=-1) if rot < d else out
@@ -360,10 +421,11 @@ def chunk_plan(n_experts: int, model_size: int) -> Tuple[int, int, int, int]:
 
 
 def moe_shapes(cfg: ModelConfig, model_size: int = 1) -> Dict[str, Any]:
-    """Expert weights in chunked [n_chunks, n_e, d, f_c] layout (EP × TP)."""
+    """Expert weights in chunked [n_chunks, n_e, d, f_c] layout (EP × TP),
+    of the experts the layer holds; the router scores all of them."""
     m = cfg.moe
     d = cfg.d_model
-    ep, tp, n_e, nc = chunk_plan(m.n_experts, model_size)
+    ep, tp, n_e, nc = chunk_plan(m.held[1], model_size)
     f_c = m.d_expert // tp
     sh: Dict[str, Any] = {
         "router": (d, m.n_experts),

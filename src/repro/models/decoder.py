@@ -21,7 +21,7 @@ from jax.sharding import PartitionSpec as P
 from .attention import gqa_attention, init_attn_cache, mla_attention
 from .common import (LayerKind, LayerPlan, ModelConfig, layer_plan, mlp_apply,
                      param_shapes, rms_norm)
-from .moe import moe_apply
+from .moe import moe_apply, no_stats
 from .ssm import init_ssm_cache, mamba2_block
 
 
@@ -84,7 +84,9 @@ def block_apply(
     cache: Optional[Dict[str, Any]] = None,
     cache_index: Optional[jax.Array] = None,
     return_cache: bool = False,
-) -> Tuple[jax.Array, Dict[str, Any]]:
+) -> Tuple[jax.Array, Dict[str, Any], Dict[str, jax.Array]]:
+    """One layer; returns ``(x, new_cache, stats)``, ``stats`` the MoE
+    layer's counts (see :func:`repro.models.moe.moe_share`), else empty."""
     eps, gm = cfg.norm_eps, cfg.gemma_norm
     # params may be stored fp32 (training master copies); compute in cfg.dtype
     cdt = cfg.compute_dtype()
@@ -134,6 +136,7 @@ def block_apply(
     else:
         raise ValueError(kind.mixer)
 
+    stats: Dict[str, jax.Array] = {}
     if kind.ffn == "dense":
         h = rms_norm(x, p["ln_mlp"], eps, gemma=gm)
         f = mlp_apply(p["mlp"], h, cfg.mlp_act)
@@ -141,17 +144,31 @@ def block_apply(
             f = rms_norm(f, p["ln_post_mlp"], eps, gemma=gm)
         x = x + f
     elif kind.ffn == "moe":
+        from repro.kernels import kernel_mode
+
         h = rms_norm(x, p["ln_mlp"], eps, gemma=gm)
-        x = x + moe_apply(
+        f, stats = moe_apply(
             p["moe"], h, cfg, mesh=ctx.mesh,
+            interpret=kernel_mode(ctx.use_kernel), stats=True,
             batch_axes=ctx.batch_axes, model_axis=ctx.model_axis,
             capacity_factor=ctx.capacity_factor,
         )
+        x = x + f
     # residual boundary: batch over the data axes and, for multi-token
     # passes on a seq-bearing mesh, sequence over the seq axis (long-context
     # prefill work is then partitioned like its KV cache)
     x = ctx.shard_act(x, ctx.batch_axes, ctx.seq_spec(x.shape[1]), None)
-    return x, new_cache
+    return x, new_cache, stats
+
+
+def _zero_stats(cfg: ModelConfig) -> Dict[str, jax.Array]:
+    """What the stack's layers count, before the first: the MoE counts for
+    a model with experts, nothing otherwise."""
+    return no_stats() if cfg.moe is not None else {}
+
+
+def _add_stats(a: Dict[str, jax.Array], b: Dict[str, jax.Array]):
+    return {k: a[k] + b[k] for k in b} if b else a
 
 
 # ---------------------------------------------------------------------------
@@ -184,11 +201,14 @@ def stack_apply(
     caches: Optional[Dict[str, Any]] = None,
     cache_index: Optional[jax.Array] = None,
     return_cache: bool = False,
-) -> Tuple[jax.Array, Optional[Dict[str, Any]]]:
+) -> Tuple[jax.Array, Optional[Dict[str, Any]], Dict[str, jax.Array]]:
+    """The layers over ``x``: ``(x, new caches or None, stats)``, ``stats``
+    the MoE layers' counts summed (empty for a model without experts)."""
     plan = layer_plan(cfg)
     kinds = plan.kinds
     shared_p = params.get("shared_attn")
     new_caches: Dict[str, Any] = {"prefix": [], "body": None, "suffix": []}
+    stats = _zero_stats(cfg)
 
     def one(kind, p, xx, cc):
         return block_apply(
@@ -200,37 +220,39 @@ def stack_apply(
     if plan.prefix:
         for i, name in enumerate(_unrolled_names(params["prefix"])):
             cc = caches["prefix"][i] if caches is not None else None
-            x, nc = one(kinds[i], params["prefix"][name], x, cc)
+            x, nc, st = one(kinds[i], params["prefix"][name], x, cc)
+            stats = _add_stats(stats, st)
             new_caches["prefix"].append(nc)
 
     # --- body (scanned over groups) -------------------------------------------
     if plan.n_groups:
-        def group_body(xx, scanned):
+        def group_body(carry, scanned):
+            xx, acc = carry
             gp, gc = scanned
             ncs = []
             for j in range(plan.period):
                 cc = None if gc is None else gc[j]
-                xx, nc = one(kinds[plan.prefix + j], gp[f"pos{j}"], xx, cc)
+                xx, nc, st = one(kinds[plan.prefix + j], gp[f"pos{j}"], xx, cc)
+                acc = _add_stats(acc, st)
                 ncs.append(nc)
-            return xx, ncs
+            return (xx, acc), ncs
 
         group_fn = _remat_wrap(group_body, ctx.remat)
         body_caches = caches["body"] if caches is not None else None
         if body_caches is None:
-            body_caches_xs = [None] * plan.period
-            xs = (params["blocks"], None)
+            def scan_fn(carry, gp):
+                carry, ncs = group_fn(carry, (gp, None))
+                return carry, ncs if return_cache else None
 
-            def scan_fn(xx, gp):
-                xx, ncs = group_fn(xx, (gp, None))
-                return xx, ncs if return_cache else None
-
-            x, ys = jax.lax.scan(scan_fn, x, params["blocks"])
+            (x, stats), ys = jax.lax.scan(scan_fn, (x, stats),
+                                          params["blocks"])
         else:
-            def scan_fn(xx, scanned):
-                xx, ncs = group_fn(xx, scanned)
-                return xx, ncs if return_cache else None
+            def scan_fn(carry, scanned):
+                carry, ncs = group_fn(carry, scanned)
+                return carry, ncs if return_cache else None
 
-            x, ys = jax.lax.scan(scan_fn, x, (params["blocks"], body_caches))
+            (x, stats), ys = jax.lax.scan(scan_fn, (x, stats),
+                                          (params["blocks"], body_caches))
         new_caches["body"] = ys
 
     # --- suffix (unrolled) ------------------------------------------------------
@@ -238,10 +260,11 @@ def stack_apply(
         for i, name in enumerate(_unrolled_names(params["suffix"])):
             li = plan.suffix_start + i
             cc = caches["suffix"][i] if caches is not None else None
-            x, nc = one(kinds[li], params["suffix"][name], x, cc)
+            x, nc, st = one(kinds[li], params["suffix"][name], x, cc)
+            stats = _add_stats(stats, st)
             new_caches["suffix"].append(nc)
 
-    return x, (new_caches if return_cache else None)
+    return x, (new_caches if return_cache else None), stats
 
 
 # ---------------------------------------------------------------------------
@@ -282,7 +305,7 @@ def forward(
     if positions is None:
         b, s = x.shape[:2]
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
-    x, _ = stack_apply(cfg, ctx, params, x, positions)
+    x, _, _ = stack_apply(cfg, ctx, params, x, positions)
     return lm_logits(cfg, ctx, params, x)
 
 
@@ -365,7 +388,7 @@ def prefill(
     if positions is None:
         b, s = x.shape[:2]
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None], (b, s))
-    x, caches = stack_apply(
+    x, caches, _ = stack_apply(
         cfg, ctx, params, x, positions, return_cache=True
     )
     logits = lm_logits(cfg, ctx, params, x[:, -1:, :])
@@ -379,8 +402,13 @@ def decode_step(
     caches: Dict[str, Any],
     tokens: jax.Array,           # [B] int32 (or embeds [B, 1, d])
     pos: jax.Array,              # () or [B] int32 — write position(s)
-) -> Tuple[jax.Array, Dict[str, Any]]:
-    """One autoregressive step over a pre-allocated cache; returns logits [B, V].
+    *,
+    return_stats: bool = False,
+):
+    """One autoregressive step over a pre-allocated cache; returns
+    ``(logits [B, V], caches)``, with ``return_stats`` also the layers'
+    stats (the MoE counts of :func:`repro.models.moe.moe_share`, summed
+    over the layers; empty for a model without experts).
 
     A scalar ``pos`` steps all sequences in lockstep; a ``[B]`` vector is
     the continuous-batching path (each session at its own depth).
@@ -399,9 +427,11 @@ def decode_step(
         positions = pos[:, None]
     if cfg.mrope_sections is not None:
         positions = jnp.broadcast_to(positions[None], (3, b, 1))
-    x, new_caches = stack_apply(
+    x, new_caches, stats = stack_apply(
         cfg, ctx, params, x, positions,
         caches=caches, cache_index=pos, return_cache=True,
     )
     logits = lm_logits(cfg, ctx, params, x)
+    if return_stats:
+        return logits[:, 0, :], new_caches, stats
     return logits[:, 0, :], new_caches

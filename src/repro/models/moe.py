@@ -1,9 +1,12 @@
 """Mixture-of-Experts FFN: reference routing + sharded EP/TP execution.
 
-Two execution paths with identical math:
+Execution paths with identical math:
 
 * :func:`moe_ref` — per-expert dense masking, exact top-k, no capacity drops.
-  Used by smoke tests / single-device runs and as the oracle.
+  The oracle.
+* :func:`moe_share` — one device: the router scores every expert, and only
+  the (token, expert) pairs routed to the experts this device holds are
+  computed, sorted by expert through grouped matmuls; no capacity drops.
 * :func:`moe_sharded` — `shard_map` over the ``model`` mesh axis.  Expert
   weights are laid out in *chunks*: the model axis is split into
   ``ep × tp`` (ep = expert parallelism, tp = tensor parallelism inside an
@@ -51,13 +54,37 @@ def router_topk(
     top_k: int,
     norm_topk: bool,
     router_scale: float,
+    n_group: int = 1,
+    topk_group: int = 1,
 ) -> Tuple[jax.Array, jax.Array]:
-    """Return (gate values [T, K] float32, expert ids [T, K] int32)."""
+    """Return (gate values [T, K] float32, expert ids [T, K] int32).
+
+    With ``n_group > 1`` the routing is group-limited (DeepSeek-V2's
+    ``group_limited_greedy``): the ``E`` scores form ``n_group`` contiguous
+    groups, each group scores its best expert, the ``topk_group`` best
+    groups are kept (lower index first on ties) and every other score is
+    zeroed before the top-k.
+    """
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    if n_group > 1:
+        t, e = probs.shape
+        by_group = probs.reshape(t, n_group, e // n_group)
+        _, keep = jax.lax.top_k(by_group.max(axis=-1), topk_group)
+        kept = jnp.zeros((t, n_group), bool).at[
+            jnp.arange(t)[:, None], keep].set(True)
+        probs = jnp.where(kept[:, :, None], by_group, 0.0).reshape(t, e)
     vals, ids = jax.lax.top_k(probs, top_k)
     if norm_topk:
         vals = vals / jnp.maximum(jnp.sum(vals, axis=-1, keepdims=True), 1e-9)
     return vals * router_scale, ids.astype(jnp.int32)
+
+
+def route(x: jax.Array, router: jax.Array, m) -> Tuple[jax.Array, jax.Array]:
+    """The layer's routing of tokens ``x`` [T, d] over all ``n_experts``."""
+    logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
+    return router_topk(logits, m.top_k, norm_topk=(m.n_shared == 0),
+                       router_scale=m.router_scale, n_group=m.n_group,
+                       topk_group=m.topk_group)
 
 
 def aux_load_balance_loss(logits: jax.Array, ids: jax.Array, n_experts: int) -> jax.Array:
@@ -74,27 +101,102 @@ def aux_load_balance_loss(logits: jax.Array, ids: jax.Array, n_experts: int) -> 
 # ---------------------------------------------------------------------------
 
 def moe_ref(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig) -> jax.Array:
-    """[B, S, d] -> [B, S, d]; loops over experts with dense masks.
+    """[B, S, d] -> [B, S, d]; loops over the held experts with dense masks.
 
     Expert weights are in the chunked layout with n_chunks=1:
-    ``experts.w_gate [1, E, d, f]`` etc.
+    ``experts.w_gate [1, E_held, d, f]`` etc.
     """
     m = cfg.moe
     b, s, d = x.shape
     xt = x.reshape(-1, d)
-    logits = xt.astype(jnp.float32) @ p["router"].astype(jnp.float32)
-    gates, ids = router_topk(logits, m.top_k, norm_topk=(m.n_shared == 0),
-                             router_scale=m.router_scale)
+    gates, ids = route(xt, p["router"], m)
     out = jnp.zeros_like(xt, dtype=jnp.float32)
     we = p["experts"]
-    for e in range(m.n_experts):
-        w = jnp.sum(jnp.where(ids == e, gates, 0.0), axis=-1)       # [T]
+    first, n_held = m.held
+    for e in range(n_held):
+        w = jnp.sum(jnp.where(ids == first + e, gates, 0.0), axis=-1)  # [T]
         h = jax.nn.silu(xt @ we["w_gate"][0, e]) * (xt @ we["w_up"][0, e])
         out = out + (h @ we["w_down"][0, e]).astype(jnp.float32) * w[:, None]
     y = out.astype(x.dtype)
     if m.n_shared:
         y = y + mlp_apply(p["shared"], xt, "swiglu")
     return y.reshape(b, s, d)
+
+
+# ---------------------------------------------------------------------------
+# One device's expert share: only the routed (token, held expert) pairs
+# ---------------------------------------------------------------------------
+
+# grouped-matmul tiles (rows, contraction, output) of the TPU kernel: each
+# group visit computes whole row tiles, so small row tiles keep the work
+# near the routed pairs when each expert sees a few tokens; on a v5e, one
+# DeepSeek-V2 expert share (256 tokens, 20 experts) took 1.74 ms against
+# 2.04 with (128, 512, 512) tiles and 3.37 through lax.ragged_dot
+GMM_TILING = (128, 2560, 768)
+
+
+def _grouped(lhs, rhs, sizes, interpret: Optional[bool]):
+    """``lhs`` rows grouped by expert (``sizes`` rows each, in order) times
+    each group's ``rhs``; rows past ``sum(sizes)`` are left undefined.
+    ``interpret`` None takes ``lax.ragged_dot``; else the megablox grouped
+    matmul kernel, compiled (False) or interpreted (True)."""
+    if interpret is None:
+        return jax.lax.ragged_dot(lhs, rhs, sizes)
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, k = lhs.shape
+    tm = min(GMM_TILING[0], -(-m // 8) * 8)
+    pad = -m % tm
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    tiling = (tm, min(GMM_TILING[1], k), min(GMM_TILING[2], rhs.shape[2]))
+    out = gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype,
+              tiling=tiling, interpret=interpret)
+    return out[:m]
+
+
+def moe_share(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig,
+              interpret: Optional[bool] = None
+              ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """[B, S, d] -> ([B, S, d], stats): the routed experts this device
+    holds (``cfg.moe.held``) and the shared experts, with no capacity.
+
+    The router scores all ``n_experts``; the (token, choice) pairs routed
+    to a held expert are sorted by expert and run through one grouped
+    matmul per weight, so the work grows with those pairs; the other
+    pairs contribute nothing.  ``stats``: ``moe_pairs`` (pairs served) and
+    ``moe_experts`` (held experts with at least one pair), int32.
+    """
+    m = cfg.moe
+    b, s, d = x.shape
+    xt = x.reshape(-1, d)
+    first, n_held = m.held
+    with jax.named_scope("repro.moe.route"):
+        gates, ids = route(xt, p["router"], m)
+        local = ids.reshape(-1) - first                     # [T*K]
+        held = (local >= 0) & (local < n_held)
+        key = jnp.where(held, local, n_held)
+        order = jnp.argsort(key, stable=True)
+        sizes = jnp.zeros((n_held + 1,), jnp.int32).at[key].add(1)[:n_held]
+        served = jnp.sum(sizes)
+        xs = jnp.take(xt, order // m.top_k, axis=0)         # [T*K, d]
+    with jax.named_scope("repro.moe.experts"):
+        we = p["experts"]
+        h = (jax.nn.silu(_grouped(xs, we["w_gate"][0], sizes, interpret))
+             * _grouped(xs, we["w_up"][0], sizes, interpret))
+        o = _grouped(h, we["w_down"][0], sizes, interpret)
+        live = jnp.arange(o.shape[0]) < served
+        g = jnp.where(held, gates.reshape(-1), 0.0)[order]
+        o = jnp.where(live[:, None], o.astype(jnp.float32) * g[:, None], 0.0)
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        y = o[back].reshape(-1, m.top_k, d).sum(axis=1).astype(x.dtype)
+    if m.n_shared:
+        with jax.named_scope("repro.moe.shared"):
+            y = y + mlp_apply(p["shared"], xt, "swiglu")
+    stats = {"moe_pairs": served,
+             "moe_experts": jnp.sum(sizes > 0).astype(jnp.int32)}
+    return y.reshape(b, s, d), stats
 
 
 # ---------------------------------------------------------------------------
@@ -158,9 +260,7 @@ def _moe_local(
     t_loc, d = x_loc.shape
     acc_dt = x_loc.dtype   # accumulate in compute dtype: keeps the backward
     # cotangent chain (and its psum over the model axis) out of fp32
-    logits = x_loc.astype(jnp.float32) @ router.astype(jnp.float32)
-    gates, ids = router_topk(logits, m.top_k, norm_topk=(m.n_shared == 0),
-                             router_scale=m.router_scale)
+    gates, ids = route(x_loc, router, m)
 
     # slot assignment: for each (token, k) choice, its position among all
     # choices of the same expert (arrival order), for capacity dropping
@@ -307,9 +407,7 @@ def _moe_local_a2a(
     cap_e = capacity // n_e                               # per (src, expert)
     t_loc, d = x_loc.shape
     acc_dt = x_loc.dtype
-    logits = x_loc.astype(jnp.float32) @ router.astype(jnp.float32)
-    gates, ids = router_topk(logits, m.top_k, norm_topk=(m.n_shared == 0),
-                             router_scale=m.router_scale)
+    gates, ids = route(x_loc, router, m)
 
     flat_ids = ids.reshape(-1)                            # [T*K]
     flat_gates = gates.reshape(-1)
@@ -470,6 +568,12 @@ def dispatch_verdict(cfg: ModelConfig, tokens_per_device: int,
     return v
 
 
+def no_stats() -> Dict[str, jax.Array]:
+    """The stats of a step that served no routed pair."""
+    return {"moe_pairs": jnp.zeros((), jnp.int32),
+            "moe_experts": jnp.zeros((), jnp.int32)}
+
+
 def moe_apply(
     p: Dict[str, Any],
     x: jax.Array,
@@ -477,10 +581,14 @@ def moe_apply(
     mesh: Optional[jax.sharding.Mesh] = None,
     *,
     dispatch: str = "auto",
+    interpret: Optional[bool] = None,
+    stats: bool = False,
     **kw,
-) -> jax.Array:
+):
     """MoE layer entry point with autotuned dispatch.
 
+    One device (no mesh, or a unit model axis) runs :func:`moe_share`, with
+    ``interpret`` its grouped-matmul choice.  On a model axis,
     ``dispatch``: ``"auto"`` consults the cached
     :func:`repro.dist.locality.price_moe_dispatch` verdict for this
     (tokens_per_device, ep_degree, tp_degree) cell — token a2a when the
@@ -489,7 +597,9 @@ def moe_apply(
     path.  The a2a path covers every chunk layout (tp > 1 dispatches to
     expert chunks with a partial psum combine) and every token count
     (ragged batches are padded and masked), so the forced path is taken
-    verbatim.
+    verbatim.  With ``stats`` returns ``(y, stats)``, ``stats`` as
+    :func:`moe_share` counts them (zero on a model axis, which counts
+    nothing).
     """
     # the dispatch-verdict span fires at jit-trace time — one event per
     # compiled (shape, path) cell, stamped at the recorder's last set_time;
@@ -499,9 +609,10 @@ def moe_apply(
     tr = obs_trace.TRACE
     if mesh is None or mesh.shape.get("model", 1) == 1:
         if tr.enabled:
-            tr.span("moe-dispatch", "moe", tr.time, 0.0, path="ref",
+            tr.span("moe-dispatch", "moe", tr.time, 0.0, path="share",
                     tokens=int(x.shape[0] * x.shape[1]))
-        return moe_ref(p, x, cfg)
+        y, st = moe_share(p, x, cfg, interpret)
+        return (y, st) if stats else y
     if dispatch not in ("auto", "a2a", "replicate"):
         raise ValueError(f"unknown moe dispatch {dispatch!r}")
     use_a2a = False
@@ -520,5 +631,7 @@ def moe_apply(
                 path="a2a" if use_a2a else "replicate",
                 tokens=int(x.shape[0] * x.shape[1]), ep=ep, tp=tp)
     if use_a2a:
-        return moe_sharded_a2a(p, x, cfg, mesh, **kw)
-    return moe_sharded(p, x, cfg, mesh, **kw)
+        y = moe_sharded_a2a(p, x, cfg, mesh, **kw)
+    else:
+        y = moe_sharded(p, x, cfg, mesh, **kw)
+    return (y, no_stats()) if stats else y

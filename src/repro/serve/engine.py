@@ -97,6 +97,11 @@ class RealBackend:
     build to the argmax back on the host, through ``host_clock`` (the
     ``repro.serve.decode`` span); the engine puts each step's share into
     its token-latency histograms.
+
+    For a model with experts, ``moe_pairs`` and ``moe_experts`` count what
+    the steps' expert layers served (routed pairs to held experts, held
+    experts with a pair; summed over layers and steps), read back with the
+    argmax; ``None`` otherwise.
     """
 
     def __init__(self, cfg, ctx, params, n_pods: int, n_slots: int,
@@ -129,13 +134,19 @@ class RealBackend:
         self.seq_shards = self.stores[0].seq_shards
         self._jnp = jnp
         self._put = jax.device_put
+        self._get = jax.device_get
+
+        experts = cfg.moe is not None
 
         def step(params, caches, tokens, pos):
-            return decoder.decode_step(cfg, ctx, params, caches, tokens, pos)
+            return decoder.decode_step(cfg, ctx, params, caches, tokens, pos,
+                                       return_stats=experts)
 
         self._step = jax.jit(step)
         self.host_clock = HostClock()
         self.decode_s = Counter("decode_s", 0.0)
+        self.moe_pairs = Counter("moe_pairs") if experts else None
+        self.moe_experts = Counter("moe_experts") if experts else None
 
     def ensure(self, pod: int, sid: int, length: int) -> None:
         st = self.stores[pod]
@@ -181,10 +192,17 @@ class RealBackend:
                 pos[s.slot] = s.length
             tokens, pos = self._put(tokens, dev), self._put(pos, dev)
         with host_span("repro.serve.dispatch"):
-            logits, st.caches = self._step(
+            logits, st.caches, *stats = self._step(
                 self.pod_params[pod], st.caches, tokens, pos)
         with host_span("repro.readback"):
-            nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            if self.moe_pairs is None:
+                nxt = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
+            else:
+                nxt, counts = self._get(
+                    (jnp.argmax(logits, axis=-1), stats[0]))
+                nxt = np.asarray(nxt, np.int32)
+                self.moe_pairs.value += int(counts["moe_pairs"])
+                self.moe_experts.value += int(counts["moe_experts"])
         out = {}
         for sid in sids:
             s = st.sessions[sid]

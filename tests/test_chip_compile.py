@@ -142,3 +142,70 @@ def test_glm4_cut_decode_step_fits_one_chip(one_chip):
              + (pods + 1) * layers * c_bytes               # caches + step out
              + ma.temp_size_in_bytes)
     assert total < HBM_BYTES, total
+
+
+def _dsv2_cut(n_layers):
+    """DeepSeek-V2 at published widths holding routing group 0 (experts
+    0-19), as the benchmark's cell runs it, ``n_layers`` deep."""
+    from repro.configs import get_config
+
+    full = get_config("deepseek-v2-236b")
+    return dataclasses.replace(full, n_layers=n_layers, moe=dataclasses.replace(
+        full.moe, held_first=0, n_held=20))
+
+
+def test_dsv2_mla_decode_step_fits_one_chip(one_chip):
+    """The dense first layer and one MoE layer of DeepSeek-V2's decode step
+    (published widths, 256 slots x 2048 positions, bf16) compile for v5e;
+    MLA's absorbed decode reads the latent cache in bf16 (no float32 copy
+    of it); scaled to the cell's five layers, its memory fits the chip."""
+    from repro.models import decoder
+    from repro.models.common import init_params
+
+    cfg = _dsv2_cut(2)
+    slots, max_len, layers = 256, 2048, 5
+    on_chip = lambda t: jax.tree.map(  # noqa: E731
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        t)
+    params = on_chip(jax.eval_shape(
+        lambda k: init_params(cfg, k, dtype=cfg.compute_dtype()),
+        jax.random.PRNGKey(0)))
+    caches = on_chip(jax.eval_shape(
+        lambda: decoder.init_cache(cfg, slots, max_len, jnp.bfloat16)))
+    ctx = decoder.RunCtx(mesh=None, use_kernel="ref")
+
+    def step(params, caches, tokens, pos):
+        return decoder.decode_step(cfg, ctx, params, caches, tokens, pos,
+                                   return_stats=True)
+
+    ids = _spec(one_chip, (slots,), jnp.int32)
+    compiled = _compile(step, params, caches, ids, ids)
+    text = compiled.as_text()
+    assert f"f32[{slots},{max_len},512]" not in text
+    assert f"f32[{slots},{max_len},64]" not in text
+    ma = compiled.memory_analysis()
+    nbytes = lambda t: sum(x.size * x.dtype.itemsize  # noqa: E731
+                           for x in jax.tree.leaves(t))
+    p_bytes, c_bytes = nbytes(params), nbytes(caches)
+    assert ma.argument_size_in_bytes >= p_bytes + c_bytes
+    moe_layer = nbytes(params["blocks"])
+    total = (p_bytes + (layers - 2) * moe_layer             # weights
+             + 2 * (layers / 2) * c_bytes                  # caches + step out
+             + ma.temp_size_in_bytes)
+    assert total < HBM_BYTES, total
+
+
+def test_dsv2_expert_share_kernel_compiles_for_v5e(one_chip):
+    """The expert share of one DeepSeek-V2 MoE layer (20 held experts of
+    160, top-6 over 8 groups, 256 tokens) through the grouped-matmul
+    kernel compiles for v5e."""
+    from repro.models import moe
+    from repro.models.common import param_shapes
+
+    cfg = _dsv2_cut(2)
+    shapes = param_shapes(cfg)["blocks"]["pos0"]["moe"]
+    p = jax.tree.map(lambda s: _spec(one_chip, s[1:], jnp.bfloat16), shapes,
+                     is_leaf=lambda s: isinstance(s, tuple))
+    x = _spec(one_chip, (256, 1, cfg.d_model), jnp.bfloat16)
+    c = _compile(lambda p, x: moe.moe_share(p, x, cfg, interpret=False), p, x)
+    assert "tpu_custom_call" in c.as_text()
