@@ -26,14 +26,28 @@ from .common import ModelConfig, apply_rope, rms_norm, yarn_mscale
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
 
-def _cache_update(buf: jax.Array, new: jax.Array, index) -> jax.Array:
-    """Write ``new`` into the seq axis (1) at scalar or per-row ``index``."""
-    new = new.astype(buf.dtype)
-    if jnp.ndim(index) == 0:
-        return jax.lax.dynamic_update_slice_in_dim(buf, new, index, axis=1)
-    return jax.vmap(
-        lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(c, n, i, axis=0)
-    )(buf, new, index.astype(jnp.int32))
+def write_rows(buf: jax.Array, new: jax.Array, index, layer=None) -> jax.Array:
+    """Write each slot's one new row ``new`` [B, 1, ...] into the cache
+    ``buf`` at its position ``index`` (scalar or [B]): one scatter at
+    (slot, index), or at (layer, slot, index) into a layer-stacked
+    [G, B, S, ...] buffer, which XLA updates in place.  A position past
+    the end writes the last row, as ``dynamic_update_slice`` clamps."""
+    b, s = new.shape[:2]
+    if s != 1:
+        raise ValueError(f"one new row per slot, got {s}")
+    slots = jnp.arange(b, dtype=jnp.int32)
+    pos = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (b,))
+    at = (slots, pos) if layer is None else (layer, slots, pos)
+    return buf.at[at].set(new[:, 0].astype(buf.dtype), mode="clip",
+                          unique_indices=True)
+
+
+def layer_view(buf: jax.Array, layer=None) -> jax.Array:
+    """The one layer's [B, S, ...] cache: ``buf`` itself, or its ``layer``
+    entry of a layer-stacked buffer."""
+    if layer is None:
+        return buf
+    return jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
 
 
 def _shard_kv(ctx, arr: jax.Array) -> jax.Array:
@@ -170,11 +184,14 @@ def gqa_attention(
     return_cache: bool = False,
     use_kernel: str = "auto",
     ctx=None,
+    layer=None,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
     """One GQA attention block (no residual / norm — the caller owns those).
 
-    ``cache`` (decode/prefill): dict(k=[B, S_max, Hkv, D], v=...).  In decode,
-    ``x`` is [B, 1, d] and ``cache_index`` is the write offset.
+    ``cache`` (decode): dict(k=[B, S_max, Hkv, D], v=...), or with
+    ``layer`` the whole layer-stacked [G, B, S_max, Hkv, D] buffers.  ``x``
+    is [B, 1, d]; its k/v rows are written at ``cache_index`` and the
+    returned cache is the written buffers (the stack, with ``layer``).
 
     When the head count does not divide the model mesh axis (e.g. 24 heads
     on a 16-way axis), head TP is impossible without splitting head_dim —
@@ -208,12 +225,15 @@ def gqa_attention(
         # decode: append to the cache ring.  cache_index is a scalar (all
         # sequences aligned) or a [B] vector (continuous batching).
         b = x.shape[0]
-        k_all = _shard_kv(ctx, _cache_update(cache["k"], k, cache_index))
-        v_all = _shard_kv(ctx, _cache_update(cache["v"], v, cache_index))
+        k_buf = write_rows(cache["k"], k, cache_index, layer)
+        v_buf = write_rows(cache["v"], v, cache_index, layer)
         if return_cache:
-            new_cache = {"k": k_all, "v": v_all}
-        kv_pos = jnp.arange(cache["k"].shape[1], dtype=jnp.int32)[None, :]
-        kv_pos = jnp.broadcast_to(kv_pos, (b, cache["k"].shape[1]))
+            new_cache = {"k": k_buf, "v": v_buf}
+        k_all = _shard_kv(ctx, layer_view(k_buf, layer))
+        v_all = _shard_kv(ctx, layer_view(v_buf, layer))
+        skv = k_all.shape[1]
+        kv_pos = jnp.broadcast_to(jnp.arange(skv, dtype=jnp.int32)[None, :],
+                                  (b, skv))
         # entries beyond the current write point are invalid -> mask via pos
         valid_upto = cache_index + x.shape[1]
         if jnp.ndim(valid_upto) == 1:
@@ -276,15 +296,18 @@ def mla_attention(
     use_kernel: str = "auto",
     is_global: bool = True,
     ctx=None,
+    layer=None,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
+    """``cache`` and ``layer`` as :func:`gqa_attention`'s, with the latent
+    ``c_kv`` [.., B, S_max, kv_lora] and rope key ``k_pe`` leaves."""
     with jax.named_scope("repro.mla"):
         return _mla(p, x, cfg, positions, cache=cache,
                     cache_index=cache_index, return_cache=return_cache,
-                    use_kernel=use_kernel, ctx=ctx)
+                    use_kernel=use_kernel, ctx=ctx, layer=layer)
 
 
 def _mla(p, x, cfg, positions, *, cache, cache_index, return_cache,
-         use_kernel, ctx):
+         use_kernel, ctx, layer):
     m = cfg.mla
     b, s, _ = x.shape
     h = cfg.n_heads
@@ -306,12 +329,11 @@ def _mla(p, x, cfg, positions, *, cache, cache_index, return_cache,
 
     q_pos = positions[0] if positions.ndim == 3 else positions
     if cache is not None and cache_index is not None:
-        c_all = _shard_kv(ctx, _cache_update(cache["c_kv"], c_kv, cache_index))
-        pe_all = _shard_kv(ctx, _cache_update(cache["k_pe"], k_pe, cache_index))
-        if return_cache:
-            new_cache = {"c_kv": c_all, "k_pe": pe_all}
-        else:
-            new_cache = None
+        c_buf = write_rows(cache["c_kv"], c_kv, cache_index, layer)
+        pe_buf = write_rows(cache["k_pe"], k_pe, cache_index, layer)
+        new_cache = {"c_kv": c_buf, "k_pe": pe_buf} if return_cache else None
+        c_all = _shard_kv(ctx, layer_view(c_buf, layer))
+        pe_all = _shard_kv(ctx, layer_view(pe_buf, layer))
         skv = c_all.shape[1]
         kv_pos = jnp.broadcast_to(jnp.arange(skv, dtype=jnp.int32)[None, :], (b, skv))
         valid_upto = cache_index + s

@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .attention import gqa_attention, init_attn_cache, mla_attention
+from .attention import (gqa_attention, init_attn_cache, layer_view,
+                        mla_attention)
 from .common import (LayerKind, LayerPlan, ModelConfig, layer_plan, mlp_apply,
                      param_shapes, rms_norm)
 from .moe import moe_apply, no_stats
@@ -84,9 +85,15 @@ def block_apply(
     cache: Optional[Dict[str, Any]] = None,
     cache_index: Optional[jax.Array] = None,
     return_cache: bool = False,
+    layer: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Dict[str, Any], Dict[str, jax.Array]]:
     """One layer; returns ``(x, new_cache, stats)``, ``stats`` the MoE
-    layer's counts (see :func:`repro.models.moe.moe_share`), else empty."""
+    layer's counts (see :func:`repro.models.moe.moe_share`), else empty.
+
+    With ``layer``, ``cache`` holds the scanned body's layer-stacked
+    buffers and this layer writes its entry of them: its attention rows at
+    (layer, slot, position), its whole mamba state at ``layer``.
+    """
     eps, gm = cfg.norm_eps, cfg.gemma_norm
     # params may be stored fp32 (training master copies); compute in cfg.dtype
     cdt = cfg.compute_dtype()
@@ -103,6 +110,7 @@ def block_apply(
         return_cache=return_cache,
         use_kernel=ctx.use_kernel,
         ctx=ctx,
+        layer=layer,
     )
 
     if kind.mixer in ("attn", "attn_local"):
@@ -124,14 +132,19 @@ def block_apply(
             new_cache["attn"] = c
     elif kind.mixer == "mamba":
         h = rms_norm(x, p["ln_mix"], eps, gemma=gm)
+        mc = None if cache is None else cache["mamba"]
         y, c = mamba2_block(
             p["mamba"], h, cfg,
-            cache=None if cache is None else cache.get("mamba"),
+            cache=None if mc is None else jax.tree.map(
+                lambda a: layer_view(a, layer), mc),
             return_cache=return_cache,
             use_kernel=ctx.use_kernel,
         )
         x = x + y
         if return_cache:
+            if layer is not None:
+                c = jax.tree.map(
+                    lambda a, n: a.at[layer].set(n.astype(a.dtype)), mc, c)
             new_cache["mamba"] = c
     else:
         raise ValueError(kind.mixer)
@@ -203,17 +216,25 @@ def stack_apply(
     return_cache: bool = False,
 ) -> Tuple[jax.Array, Optional[Dict[str, Any]], Dict[str, jax.Array]]:
     """The layers over ``x``: ``(x, new caches or None, stats)``, ``stats``
-    the MoE layers' counts summed (empty for a model without experts)."""
+    the MoE layers' counts summed (empty for a model without experts).
+
+    Given ``caches`` (the decode step), the body's layer-stacked caches
+    ride the scan's carry and each layer writes only its new rows into
+    them, so a donated cache is updated in place; without, the scan
+    stacks each layer's cache as its output when ``return_cache``.
+    """
     plan = layer_plan(cfg)
     kinds = plan.kinds
     shared_p = params.get("shared_attn")
     new_caches: Dict[str, Any] = {"prefix": [], "body": None, "suffix": []}
     stats = _zero_stats(cfg)
+    return_cache = return_cache or caches is not None
 
-    def one(kind, p, xx, cc):
+    def one(kind, p, xx, cc, layer=None):
         return block_apply(
             cfg, ctx, kind, p, shared_p, xx, positions,
             cache=cc, cache_index=cache_index, return_cache=return_cache,
+            layer=layer,
         )
 
     # --- prefix (unrolled) ---------------------------------------------------
@@ -227,33 +248,24 @@ def stack_apply(
     # --- body (scanned over groups) -------------------------------------------
     if plan.n_groups:
         def group_body(carry, scanned):
-            xx, acc = carry
-            gp, gc = scanned
+            xx, acc, gc = carry
+            gp, layer = scanned
             ncs = []
             for j in range(plan.period):
                 cc = None if gc is None else gc[j]
-                xx, nc, st = one(kinds[plan.prefix + j], gp[f"pos{j}"], xx, cc)
+                xx, nc, st = one(kinds[plan.prefix + j], gp[f"pos{j}"], xx, cc,
+                                 None if gc is None else layer)
                 acc = _add_stats(acc, st)
                 ncs.append(nc)
-            return (xx, acc), ncs
+            if gc is not None:
+                return (xx, acc, ncs), None
+            return (xx, acc, None), ncs if return_cache else None
 
-        group_fn = _remat_wrap(group_body, ctx.remat)
-        body_caches = caches["body"] if caches is not None else None
-        if body_caches is None:
-            def scan_fn(carry, gp):
-                carry, ncs = group_fn(carry, (gp, None))
-                return carry, ncs if return_cache else None
-
-            (x, stats), ys = jax.lax.scan(scan_fn, (x, stats),
-                                          params["blocks"])
-        else:
-            def scan_fn(carry, scanned):
-                carry, ncs = group_fn(carry, scanned)
-                return carry, ncs if return_cache else None
-
-            (x, stats), ys = jax.lax.scan(scan_fn, (x, stats),
-                                          (params["blocks"], body_caches))
-        new_caches["body"] = ys
+        body = caches["body"] if caches is not None else None
+        (x, stats, body), ys = jax.lax.scan(
+            _remat_wrap(group_body, ctx.remat), (x, stats, body),
+            (params["blocks"], jnp.arange(plan.n_groups, dtype=jnp.int32)))
+        new_caches["body"] = ys if body is None else body
 
     # --- suffix (unrolled) ------------------------------------------------------
     if plan.suffix:

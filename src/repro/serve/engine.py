@@ -142,7 +142,9 @@ class RealBackend:
             return decoder.decode_step(cfg, ctx, params, caches, tokens, pos,
                                        return_stats=experts)
 
-        self._step = jax.jit(step)
+        # the caches are donated: each step writes its rows into the
+        # buffers it was given, and ``st.caches`` is rebound to its output
+        self._step = jax.jit(step, donate_argnums=(1,))
         self.host_clock = HostClock()
         self.decode_s = Counter("decode_s", 0.0)
         self.moe_pairs = Counter("moe_pairs") if experts else None

@@ -451,3 +451,107 @@ def test_real_backend_decode_wall_time_feeds_token_latency():
     assert eng.metrics.as_dict()["token_lat_p99_s"] == pytest.approx(0.25)
     assert eng.metrics.sim_time_s == 0.0
     assert router._now - now0 == pytest.approx(2 * REAL_STEP_MS)
+
+
+# ---------------------------------------------------------------------------
+# The donated decode step against the per-layer form it replaced
+# ---------------------------------------------------------------------------
+
+def _vmapped_write(buf, new, index, layer=None):
+    """The per-slot cache write as the decode step made it before its
+    caches were donated: each slot's row by its own
+    ``dynamic_update_slice`` into one layer's cache, sliced out of the
+    stack."""
+    assert layer is None
+    index = jnp.broadcast_to(jnp.asarray(index, jnp.int32), new.shape[:1])
+    return jax.vmap(lambda c, n, i: jax.lax.dynamic_update_slice_in_dim(
+        c, n, i, axis=0))(buf, new.astype(buf.dtype), index)
+
+
+def _per_layer_step(cfg, params, caches, tokens, pos):
+    """The oracle: one decode step run eagerly layer by layer, each layer's
+    cache taken out of the stack, written by ``_vmapped_write`` and
+    stacked back; returns ``(logits, caches)``."""
+    from unittest import mock
+
+    from repro.models import attention
+    from repro.models.common import layer_plan
+
+    plan = layer_plan(cfg)
+    tokens = jnp.asarray(tokens, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    b = tokens.shape[0]
+    positions = jnp.broadcast_to(pos, (b,))[:, None]
+    x = decoder.embed_in(cfg, params, {"tokens": tokens[:, None]})
+
+    def run(kind, p, cache):
+        nonlocal x
+        x, nc, _ = decoder.block_apply(
+            cfg, CTX, kind, p, params.get("shared_attn"), x, positions,
+            cache=jax.tree.map(jnp.asarray, cache), cache_index=pos,
+            return_cache=True)
+        return nc
+
+    take = lambda t, g: jax.tree.map(lambda a: a[g], t)  # noqa: E731
+    out = {"prefix": [], "body": None, "suffix": []}
+    with mock.patch.object(attention, "write_rows", _vmapped_write):
+        for i, name in enumerate(decoder._unrolled_names(params["prefix"])
+                                 if plan.prefix else []):
+            out["prefix"].append(run(plan.kinds[i], params["prefix"][name],
+                                     caches["prefix"][i]))
+        groups = []
+        for g in range(plan.n_groups):
+            groups.append([run(plan.kinds[plan.prefix + j],
+                               take(params["blocks"][f"pos{j}"], g),
+                               take(caches["body"][j], g))
+                           for j in range(plan.period)])
+        if groups:
+            out["body"] = [jax.tree.map(lambda *ls: jnp.stack(ls),
+                                        *[row[j] for row in groups])
+                           for j in range(plan.period)]
+        for i, name in enumerate(decoder._unrolled_names(params["suffix"])
+                                 if plan.suffix else []):
+            out["suffix"].append(run(plan.kinds[plan.suffix_start + i],
+                                     params["suffix"][name],
+                                     caches["suffix"][i]))
+    return decoder.lm_logits(cfg, CTX, params, x)[:, 0], out
+
+
+@pytest.mark.parametrize("arch", ["glm4-9b", "deepseek-v2-236b",
+                                  "zamba2-1.2b"])
+def test_donated_decode_step_matches_per_layer_writes(arch):
+    """``RealBackend``'s jitted step, its caches donated and written in
+    place, gives the logits and caches of the per-layer form it replaced:
+    GQA (glm4), MLA with a dense unrolled first layer (DeepSeek-V2), and
+    mamba layers in the scanned body and the unrolled suffix beside a
+    shared attention block (Zamba2).  Four steps with slots at different
+    depths and one slot idle (token 0 at position 0, as ``RealBackend``
+    passes it), then one step of the scalar-position path."""
+    cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32")
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    be = RealBackend(cfg, CTX, params, n_pods=1, n_slots=4, max_len=16)
+    st = be.stores[0]
+    # float32 caches, so that a float rounding apart between the jitted
+    # step and the eager oracle is not widened to a bfloat16 unit
+    st.caches = decoder.init_cache(cfg, 4, 16, jnp.float32)
+    # host copies: a zero-copy view would keep the buffers from being donated
+    want_caches = jax.tree.map(lambda a: np.array(a, copy=True), st.caches)
+    rng = np.random.default_rng(0)
+    depth = np.array([0, 3, 7, 0])           # slot 3 sits every step out
+    steps = [(t, depth + t) for t in range(4)] + [(4, np.int32(12))]
+    for t, pos in steps:
+        tokens = rng.integers(1, cfg.vocab_size, 4).astype(np.int32)
+        if np.ndim(pos):
+            tokens[3], pos = 0, np.where(np.arange(4) == 3, 0, pos)
+        donated = jax.tree.leaves(st.caches)
+        logits, st.caches, *_ = be._step(be.pod_params[0], st.caches,
+                                         jnp.asarray(tokens),
+                                         jnp.asarray(pos, jnp.int32))
+        assert all(a.is_deleted() for a in donated), t
+        want, want_caches = _per_layer_step(cfg, params, want_caches,
+                                            tokens, pos)
+        np.testing.assert_allclose(np.asarray(logits), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5, err_msg=str(t))
+        jax.tree.map(lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5,
+            err_msg=str(t)), st.caches, want_caches)
