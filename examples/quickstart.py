@@ -58,4 +58,4 @@ def part2_serving():
 if __name__ == "__main__":
     part1_cluster()
     part2_serving()
-    print("done — see benchmarks/ for the full paper evaluation.")
+    print("done — bench/run.py measures the system on the chip (README: Running).")

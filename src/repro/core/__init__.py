@@ -11,11 +11,8 @@ library:
 * a TL2-style local STM with batched JAX certification (:mod:`repro.core.stm`);
 * a simulated view-synchronous GCS (:mod:`repro.core.gcs`) and the
   discrete-event cluster simulator (:mod:`repro.core.cluster`) that together
-  reproduce the paper's evaluation;
-* a vectorized `lax.scan` cluster model (:mod:`repro.core.jax_sim`) for wide
-  policy sweeps.
+  reproduce the paper's evaluation.
 """
-from . import jax_sim
 from .conflict import ConflictClassMap
 from .cluster import Cluster, Metrics, SimConfig, TxnSpec, Workload
 from .dtd import DTD, DTDConfig, C_AB, C_P2P, C_URB

@@ -143,11 +143,14 @@ class SimConfig:
     # start* and the request round + the owner's in-flight commit drain
     # overlap the transaction's own execution; commit certification still
     # waits for both execution and enablement, so safety is untouched (the
-    # explorer's CI grid model-checks both handoffs violation-free, and
-    # benchmarks/handoff.py pins pipelined >= drain across the locality x
-    # contention grid).  "drain" is the paper's ordering — execute, then
-    # request leases, then wait for the owner's LORs to drain — kept as
-    # the fallback knob and the oracle for the overlap's equivalence tests.
+    # explorer's CI grid model-checks both handoffs violation-free; a CPU
+    # sweep of the simulator found pipelined >= drain in simulated
+    # throughput across the locality x contention grid, and
+    # tests/test_cluster.py::test_pipelined_handoff_no_slower_than_drain
+    # pins it at locality 0.0 and 0.9).  "drain" is the paper's ordering —
+    # execute, then request leases, then wait for the owner's LORs to
+    # drain — kept as the fallback knob and the oracle for the overlap's
+    # equivalence tests.
     handoff: str = "pipelined"
     # Commit-phase slot cost.  "amortized" (default, batched mode only):
     # the group of transactions enabled together occupies ONE worker slot

@@ -35,9 +35,10 @@ C_AB = 3.0
 class DTDConfig:
     policy: str = "short"      # "short" | "long" | "opt" | "local"
     # maxCPU threshold of constraint (3).  Re-swept against the fixed
-    # CpuMeter (benchmarks/overload.py --sweep-max-cpu, 3 seeds: post-
-    # overload throughput is flat for thresholds <= 0.9 and degrades at
-    # 0.95, where the valve trips after the ~0.95 injected load): 0.9 is
+    # CpuMeter in a CPU sweep of the simulator's overload scenario (3
+    # seeds: post-overload simulated throughput is flat for thresholds
+    # <= 0.9 and degrades at 0.95, where the valve trips after the ~0.95
+    # injected load): 0.9 is
     # the combined short+long winner.  The old 0.85 was tuned while the
     # meter double-counted injected load (~2x), i.e. an effective ~0.43.
     max_cpu: float = 0.9

@@ -15,9 +15,9 @@ interconnect* and divides by bandwidth.  ``prefer_migration`` below is
 exactly the paper's "migrate the transaction" verdict: it becomes true as
 soon as the state is heavier than the work description.
 
-Interconnect constants are v5e-class defaults, intentionally shared with
-:mod:`repro.launch.hlo_analysis` where they overlap (``ICI_BW``); they are
-keyword-overridable everywhere so benchmarks can sweep them.
+Interconnect constants are v5e-class defaults, the one home of these
+hardware facts in the program (the serving engine reads ``HBM_BW`` from
+here); they are keyword-overridable everywhere so a caller can sweep them.
 """
 from __future__ import annotations
 
@@ -29,7 +29,7 @@ from dataclasses import dataclass
 # ---------------------------------------------------------------------------
 
 HBM_BW = 819e9        # HBM read bandwidth per chip
-ICI_BW = 50e9         # ICI, per link per direction (matches launch.hlo_analysis)
+ICI_BW = 50e9         # ICI, per link per direction
 ICI_LINKS = 4         # v5e: 4 links per chip (2D torus)
 PCIE_BW = 32e9        # host <-> device staging path
 DCN_BW = 25e9         # cross-pod data-center network, per pod pair
@@ -37,26 +37,27 @@ DCN_RTT_S = 1e-3      # cross-pod round-trip (the paper's P2P step constant)
 
 
 # ---------------------------------------------------------------------------
-# Router defaults — the winning thresholds of benchmarks/serve_locality.py
+# Router defaults — the winning thresholds of a CPU sweep of the simulator
 # ---------------------------------------------------------------------------
 
 @dataclass(frozen=True)
 class RouterDefaults:
     """Default knobs for :class:`repro.serve.router.LocalityRouter`.
 
-    The values are the winners of the policy×arbitration sweep in
-    ``benchmarks/serve_locality.py`` (8 pods, mixtral-8x7b KV sizes, 3
-    seeds): ``short`` step costs for new-session placement with the priced
-    byte model settling forward-vs-acquire (``priced``) ships the least
-    wire of the grid — 14% less than step-constant arbitration at locality
-    0.9 (wire_GB 0.012 vs 0.014), where it is also ~11% faster — with no
-    tokens/s regression at locality 0.0.
+    The values are the winners of a policy×arbitration sweep of the
+    simulator on the CPU (``SimBackend``, 8 pods, mixtral-8x7b KV sizes, 3
+    seeds; simulated metrics, not chip timings): ``short`` step costs for
+    new-session placement with the priced byte model settling
+    forward-vs-acquire (``priced``) ships the least wire of the grid — 14%
+    less than step-constant arbitration at locality 0.9 (wire_GB 0.012 vs
+    0.014), where its simulated tokens/s is also ~11% higher — with no
+    simulated tokens/s regression at locality 0.0.
     """
 
     policy: str = "short"          # DTD cost policy: "local"|"short"|"long"
     arbitration: str = "priced"    # "steps" | "priced" | "hybrid"
-    # constraint (3) threshold, re-swept against the fixed CpuMeter
-    # (benchmarks/overload.py --sweep-max-cpu; see DTDConfig.max_cpu)
+    # constraint (3) threshold, re-swept against the fixed CpuMeter in a
+    # CPU sweep of the simulator's overload scenario (see DTDConfig.max_cpu)
     max_cpu: float = 0.9
     freq_tau_ms: float = 500.0     # LC access-frequency decay constant
 

@@ -1,4 +1,4 @@
-"""Launchers: production mesh, multi-pod dry-run, train/serve drivers."""
+"""Launchers: host meshes, train/serve drivers, compile cache."""
 from __future__ import annotations
 
 import os

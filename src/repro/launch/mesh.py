@@ -1,48 +1,7 @@
-"""Production meshes.
-
-``make_production_mesh`` is a function (not a module-level constant) so that
-importing this module never touches jax device state; callers that need the
-512-placeholder-device dry-run must set ``XLA_FLAGS`` before *any* jax
-import (see ``launch/dryrun.py``).
-"""
+"""Host meshes for the serve and train drivers."""
 from __future__ import annotations
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False,
-                         seq: int = 1) -> jax.sharding.Mesh:
-    """16×16 chips per pod; the multi-pod mesh prepends a 2-pod axis.
-
-    ``seq`` > 1 splits the data axis into ``data × seq`` (e.g. ``seq=4``
-    yields a 4×4×16 pod) so long-context KV caches shard their sequence
-    dim (:mod:`repro.dist.sharding`'s long-context rule) without changing
-    the chip count per pod.
-
-    With the dry-run's 512 placeholder devices the single-pod mesh uses the
-    first 256 (one pod's worth), so both meshes are constructible in one
-    process.
-    """
-    import numpy as np
-
-    if 16 % seq:
-        raise ValueError(f"seq axis {seq} must divide the 16-wide data axis")
-    data = 16 // seq
-    if seq > 1:
-        shape = (2, data, seq, 16) if multi_pod else (data, seq, 16)
-        axes = (("pod",) if multi_pod else ()) + ("data", "seq", "model")
-    else:
-        shape = (2, 16, 16) if multi_pod else (16, 16)
-        axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    n = int(np.prod(shape))
-    devs = jax.devices()
-    if len(devs) < n:
-        raise RuntimeError(
-            f"need {n} devices for mesh {shape}, have {len(devs)} — the "
-            "dry-run entrypoint must set XLA_FLAGS=--xla_force_host_platform_"
-            "device_count=512 before any jax import"
-        )
-    return jax.sharding.Mesh(np.asarray(devs[:n]).reshape(shape), axes)
 
 
 def make_host_mesh(model: int = 1, seq: int = 1) -> jax.sharding.Mesh:

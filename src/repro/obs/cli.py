@@ -1,6 +1,6 @@
 """``repro-trace``: export / summarize / diff repro.obs timelines.
 
-- ``export``  — run the seeded serve_locality smoke scenario with tracing
+- ``export``  — run the seeded serving-locality smoke scenario with tracing
   on (engine routing, lease acquires, certify batches, decode spans,
   planner epochs) plus one tiny MoE forward (the jit-trace-time dispatch
   verdict), and write the combined Perfetto ``trace_event`` JSON.
@@ -27,7 +27,7 @@ from repro.obs import trace as obs_trace
 def _run_serve_smoke(rec, *, arch: str, pods: int, sessions: int,
                      steps: int, locality: float, seed: int,
                      plan_epoch_ms: float) -> dict:
-    """The serve_locality smoke loop with the recorder threaded through."""
+    """The serving-locality smoke loop with the recorder threaded through."""
     import numpy as np
 
     from repro.configs import get_config
@@ -98,7 +98,7 @@ def _run_moe_smoke(arch: str, seed: int) -> None:
 def _cmd_export(argv: List[str]) -> int:
     ap = argparse.ArgumentParser(
         prog="repro-trace export",
-        description="Run the seeded serve_locality smoke with tracing on "
+        description="Run the seeded serving-locality smoke with tracing on "
                     "and export a Perfetto trace.")
     ap.add_argument("--out", default="trace.json")
     ap.add_argument("--arch", default="mixtral-8x7b")

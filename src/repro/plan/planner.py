@@ -54,8 +54,9 @@ class PlanConfig:
 
 
 # Serving: epochs are engine sim-time ms (a pod step is ~0.1–0.5 ms), moves
-# ship real KV bytes — tight budget, strict evidence gates.  Winners of the
-# benchmarks/planner.py sweep (mixtral KV sizes, 3 seeds): vs ROUTER_DEFAULTS
+# ship real KV bytes — tight budget, strict evidence gates.  Winners of a
+# CPU sweep of the simulator (SimBackend, mixtral KV sizes, 3 seeds;
+# simulated metrics, not chip timings): vs ROUTER_DEFAULTS
 # the planner cuts total wire 4.6–7.5× and forwards 8–26% at locality ≥ 0.7
 # with tokens/s parity at locality 0 (where the gates keep it idle).
 SERVE_PLAN_DEFAULTS = PlanConfig(

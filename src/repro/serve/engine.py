@@ -5,8 +5,9 @@ Two backends behind one engine:
 * :class:`RealBackend` — actually decodes with the JAX model (per-session
   positions, KV slots) on the process's devices: every pod on the default
   device, or each pod on a chip of its own as a one-chip replica.
-* :class:`SimBackend` — prices each pod-step with the roofline model;
-  used by the pod-scale benchmarks where 256-chip pods are simulated.
+* :class:`SimBackend` — prices each pod-step with the roofline model; a
+  count model for pod-scale routing (``--backend sim``, the tests), whose
+  prices are projections, not measured speed.
 
 Per engine step: (1) the geo load-balancer assigns incoming requests to
 origin pods, (2) the :class:`LocalityRouter` (the paper's DTD) picks
@@ -33,8 +34,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from repro.dist.locality import DCN_RTT_S, price_session_dispatch
-from repro.launch.hlo_analysis import HBM_BW
+from repro.dist.locality import DCN_RTT_S, HBM_BW, price_session_dispatch
 from repro.obs.metrics import Counter, MetricSet
 from repro.obs.trace import HostClock, TraceRecorder, host_span
 from .certifier import StepCertifier

@@ -135,9 +135,9 @@ def _served_step(one_chip, cfg, slots, max_len):
 
 
 def test_glm4_cut_decode_step_fits_one_chip(one_chip):
-    """One layer of chip_smoke's glm4-9b decode step (published widths, one
-    pod of 16 slots x 4096 tokens, bf16) compiles for v5e; scaled to the
-    smoke's 16 layers and two pods, its memory fits the chip."""
+    """One layer of a glm4-9b decode step (published widths, one pod of
+    16 slots x 4096 tokens, bf16) compiles for v5e; scaled to 16 layers
+    and two pods, its memory fits the chip."""
     from repro.configs import get_config
 
     full = get_config("glm4-9b")

@@ -61,6 +61,23 @@ def test_migration_helps_at_low_locality():
     assert thr["LILAC-TM-ST"] > 1.15 * thr["ALC"]
 
 
+@pytest.mark.parametrize("locality", [0.0, 0.9])
+def test_pipelined_handoff_no_slower_than_drain(locality):
+    """The pipelined handoff (lease request overlapped with execution) never
+    costs simulated throughput against the paper's drain ordering.  The
+    cells tie within scheduler-ordering jitter, hence the 0.99 floor."""
+    thr = {}
+    for handoff in ("drain", "pipelined"):
+        cfg = SimConfig(duration_ms=400.0, warmup_ms=60.0, threads_per_node=2,
+                        seed=0, handoff=handoff)
+        wl = BankWorkload(n_nodes=cfg.n_nodes, n_items=cfg.n_items,
+                          locality=locality)
+        c = make_cluster("FGL", wl, cfg)
+        c.run()
+        thr[handoff] = c.throughput()
+    assert thr["pipelined"] >= 0.99 * thr["drain"]
+
+
 def test_lease_reuse_rate_tracks_locality():
     lo = _bank("FGL", locality=0.1, duration=400.0)
     hi = _bank("FGL", locality=0.95, duration=400.0)

@@ -61,7 +61,7 @@ def test_cache_pspecs_congruent_with_init_cache(arch):
     specs = shd.cache_pspecs(cfg, mesh, tree, batch)
     assert jax.tree.structure(tree) == jax.tree.structure(
         specs, is_leaf=lambda x: isinstance(x, P))
-    # the dryrun zip: struct tree × spec tree -> sharded struct tree
+    # zip the struct tree with the spec tree -> sharded struct tree
     structs = jax.tree.map(
         lambda s, p: jax.ShapeDtypeStruct(
             s.shape, s.dtype, sharding=NamedSharding(mesh, p)),

@@ -7,7 +7,6 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core.conflict import ConflictClassMap
 from repro.core.lease import FGLLeaseManager, LeaseRequest
-from repro.launch import hlo_count
 
 
 # ---------------------------------------------------------------------------
@@ -91,15 +90,3 @@ def test_conflict_map_total_and_stable(n_classes, stride, items):
     for i in items:
         assert m.of_item(i) == m.of_item(i)
 
-
-# ---------------------------------------------------------------------------
-# HLO shape parsing
-# ---------------------------------------------------------------------------
-
-@settings(max_examples=50, deadline=None)
-@given(st.lists(st.integers(1, 64), min_size=0, max_size=4),
-       st.sampled_from(["f32", "bf16", "s32", "pred", "u8"]))
-def test_hlo_shape_elems(dims, dtype):
-    ty = f"{dtype}[{','.join(map(str, dims))}]{{0}}"
-    n = hlo_count._shape_elems(ty)
-    assert n == int(np.prod(dims)) if dims else n == 1
