@@ -92,7 +92,9 @@ def block_apply(
 
     With ``layer``, ``cache`` holds the scanned body's layer-stacked
     buffers and this layer writes its entry of them: its attention rows at
-    (layer, slot, position), its whole mamba state at ``layer``.
+    (layer, slot, position), its whole mamba state at ``layer``; an MoE
+    layer's ``p["moe"]["experts"]`` is then the body's whole stack of them
+    (see :func:`repro.models.moe.moe_share`).
     """
     eps, gm = cfg.norm_eps, cfg.gemma_norm
     # params may be stored fp32 (training master copies); compute in cfg.dtype
@@ -162,7 +164,7 @@ def block_apply(
         h = rms_norm(x, p["ln_mlp"], eps, gemma=gm)
         f, stats = moe_apply(
             p["moe"], h, cfg, mesh=ctx.mesh,
-            interpret=kernel_mode(ctx.use_kernel), stats=True,
+            interpret=kernel_mode(ctx.use_kernel), stats=True, layer=layer,
             batch_axes=ctx.batch_axes, model_axis=ctx.model_axis,
             capacity_factor=ctx.capacity_factor,
         )
@@ -204,6 +206,20 @@ def _unrolled_names(params_sub: Dict[str, Any]) -> list:
     return sorted(params_sub, key=lambda s: int(s.removeprefix("layer")))
 
 
+def _lift_experts(blocks: Dict[str, Any]):
+    """``(blocks without their MoE experts, {position: stacked experts})``:
+    a slice of the stack cannot feed the grouped-matmul kernel in place, so
+    the body takes the experts whole and the scan slices the rest."""
+    rest, experts = {}, {}
+    for name, bp in blocks.items():
+        if "moe" in bp:
+            experts[name] = bp["moe"]["experts"]
+            bp = dict(bp, moe={k: v for k, v in bp["moe"].items()
+                               if k != "experts"})
+        rest[name] = bp
+    return rest, experts
+
+
 def stack_apply(
     cfg: ModelConfig,
     ctx: RunCtx,
@@ -220,8 +236,10 @@ def stack_apply(
 
     Given ``caches`` (the decode step), the body's layer-stacked caches
     ride the scan's carry and each layer writes only its new rows into
-    them, so a donated cache is updated in place; without, the scan
-    stacks each layer's cache as its output when ``return_cache``.
+    them, so a donated cache is updated in place, and its MoE layers'
+    grouped matmuls read their experts in place in the stacked weights,
+    which stay out of the scanned slices; without, the scan stacks each
+    layer's cache as its output when ``return_cache``.
     """
     plan = layer_plan(cfg)
     kinds = plan.kinds
@@ -247,13 +265,21 @@ def stack_apply(
 
     # --- body (scanned over groups) -------------------------------------------
     if plan.n_groups:
+        blocks, experts = params["blocks"], {}
+        if caches is not None:
+            blocks, experts = _lift_experts(blocks)
+
         def group_body(carry, scanned):
             xx, acc, gc = carry
             gp, layer = scanned
             ncs = []
             for j in range(plan.period):
                 cc = None if gc is None else gc[j]
-                xx, nc, st = one(kinds[plan.prefix + j], gp[f"pos{j}"], xx, cc,
+                pj = gp[f"pos{j}"]
+                if f"pos{j}" in experts:
+                    pj = dict(pj, moe=dict(pj["moe"],
+                                           experts=experts[f"pos{j}"]))
+                xx, nc, st = one(kinds[plan.prefix + j], pj, xx, cc,
                                  None if gc is None else layer)
                 acc = _add_stats(acc, st)
                 ncs.append(nc)
@@ -264,7 +290,7 @@ def stack_apply(
         body = caches["body"] if caches is not None else None
         (x, stats, body), ys = jax.lax.scan(
             _remat_wrap(group_body, ctx.remat), (x, stats, body),
-            (params["blocks"], jnp.arange(plan.n_groups, dtype=jnp.int32)))
+            (blocks, jnp.arange(plan.n_groups, dtype=jnp.int32)))
         new_caches["body"] = ys if body is None else body
 
     # --- suffix (unrolled) ------------------------------------------------------

@@ -138,6 +138,10 @@ GMM_TILING = (128, 2560, 768)
 def _grouped(lhs, rhs, sizes, interpret: Optional[bool]):
     """``lhs`` rows grouped by expert (``sizes`` rows each, in order) times
     each group's ``rhs``; rows past ``sum(sizes)`` are left undefined.
+    ``rhs`` is one layer's experts ``[E, k, n]``, or a scanned body's stack
+    of them ``[G*E, k, n]`` with ``sizes`` of length ``G*E`` that are zero
+    outside the layer's block: empty groups cost no work and the kernel
+    reads the layer's experts where they lie in the stack.
     ``interpret`` None takes ``lax.ragged_dot``; else the megablox grouped
     matmul kernel, compiled (False) or interpreted (True)."""
     if interpret is None:
@@ -156,7 +160,8 @@ def _grouped(lhs, rhs, sizes, interpret: Optional[bool]):
 
 
 def moe_share(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig,
-              interpret: Optional[bool] = None
+              interpret: Optional[bool] = None,
+              layer: Optional[jax.Array] = None,
               ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     """[B, S, d] -> ([B, S, d], stats): the routed experts this device
     holds (``cfg.moe.held``) and the shared experts, with no capacity.
@@ -166,6 +171,11 @@ def moe_share(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig,
     matmul per weight, so the work grows with those pairs; the other
     pairs contribute nothing.  ``stats``: ``moe_pairs`` (pairs served) and
     ``moe_experts`` (held experts with at least one pair), int32.
+
+    ``p["experts"]`` holds this layer's experts ``[1, E_held, ...]``; with
+    ``layer``, a scanned body's stack of every layer's ``[G, 1, E_held,
+    ...]``, of which the grouped matmuls read block ``layer`` in place
+    (the group sizes are those of ``G*E_held`` experts, zero outside it).
     """
     m = cfg.moe
     b, s, d = x.shape
@@ -181,10 +191,16 @@ def moe_share(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig,
         served = jnp.sum(sizes)
         xs = jnp.take(xt, order // m.top_k, axis=0)         # [T*K, d]
     with jax.named_scope("repro.moe.experts"):
-        we = p["experts"]
-        h = (jax.nn.silu(_grouped(xs, we["w_gate"][0], sizes, interpret))
-             * _grouped(xs, we["w_up"][0], sizes, interpret))
-        o = _grouped(h, we["w_down"][0], sizes, interpret)
+        we = jax.tree.map(lambda w: w.reshape(-1, *w.shape[-2:]),
+                          p["experts"])
+        gs = sizes
+        if layer is not None:
+            gs = jax.lax.dynamic_update_slice(
+                jnp.zeros(we["w_gate"].shape[:1], jnp.int32), sizes,
+                (layer * n_held,))
+        h = (jax.nn.silu(_grouped(xs, we["w_gate"], gs, interpret))
+             * _grouped(xs, we["w_up"], gs, interpret))
+        o = _grouped(h, we["w_down"], gs, interpret)
         live = jnp.arange(o.shape[0]) < served
         g = jnp.where(held, gates.reshape(-1), 0.0)[order]
         o = jnp.where(live[:, None], o.astype(jnp.float32) * g[:, None], 0.0)
@@ -583,6 +599,7 @@ def moe_apply(
     dispatch: str = "auto",
     interpret: Optional[bool] = None,
     stats: bool = False,
+    layer: Optional[jax.Array] = None,
     **kw,
 ):
     """MoE layer entry point with autotuned dispatch.
@@ -599,7 +616,8 @@ def moe_apply(
     (ragged batches are padded and masked), so the forced path is taken
     verbatim.  With ``stats`` returns ``(y, stats)``, ``stats`` as
     :func:`moe_share` counts them (zero on a model axis, which counts
-    nothing).
+    nothing).  With ``layer``, ``p["experts"]`` is a scanned body's stack
+    (see :func:`moe_share`); a model axis takes the layer's slice of it.
     """
     # the dispatch-verdict span fires at jit-trace time — one event per
     # compiled (shape, path) cell, stamped at the recorder's last set_time;
@@ -611,8 +629,10 @@ def moe_apply(
         if tr.enabled:
             tr.span("moe-dispatch", "moe", tr.time, 0.0, path="share",
                     tokens=int(x.shape[0] * x.shape[1]))
-        y, st = moe_share(p, x, cfg, interpret)
+        y, st = moe_share(p, x, cfg, interpret, layer)
         return (y, st) if stats else y
+    if layer is not None:
+        p = dict(p, experts=jax.tree.map(lambda w: w[layer], p["experts"]))
     if dispatch not in ("auto", "a2a", "replicate"):
         raise ValueError(f"unknown moe dispatch {dispatch!r}")
     use_a2a = False

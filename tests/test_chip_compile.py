@@ -111,7 +111,7 @@ def _nbytes(tree):
     return sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
 
-def _served_step(one_chip, cfg, slots, max_len):
+def _served_step(one_chip, cfg, slots, max_len, use_kernel="ref"):
     """``RealBackend``'s jitted decode step (caches donated) compiled for one
     v5e at ``slots x max_len``; returns it with the parameter and cache
     shapes.  The backend itself holds a one-slot store: only its jit is
@@ -128,7 +128,7 @@ def _served_step(one_chip, cfg, slots, max_len):
         jax.random.PRNGKey(0)))
     caches = on_chip(jax.eval_shape(
         lambda: decoder.init_cache(cfg, slots, max_len, jnp.bfloat16)))
-    be = RealBackend(cfg, decoder.RunCtx(mesh=None, use_kernel="ref"),
+    be = RealBackend(cfg, decoder.RunCtx(mesh=None, use_kernel=use_kernel),
                      params, n_pods=1, n_slots=1, max_len=8)
     ids = _spec(one_chip, (slots,), jnp.int32)
     return be._step.lower(params, caches, ids, ids).compile(), params, caches
@@ -205,15 +205,9 @@ def _dims(hlo_shape):
     return _squeeze(int(d) for d in re.findall(r"\d+", hlo_shape.split("[")[1]))
 
 
-def _layer_copies(text, layer_shapes):
-    """The instructions of compiled HLO that copy one layer's cache within
-    device memory: every dynamic update whose update or result is a
-    layer's cache, and every kernel (an instruction of a computation that
-    no fusion calls) that slices a layer's cache out into HBM.
-
-    A layer's slice staged into on-chip memory (memory space ``S(1)``)
-    for the attention's dot is the one read of it the step must make, and
-    is not counted."""
+def _computations(text):
+    """``({computation: {instruction: (shape, layout, op, rest)}}, the
+    computations that fusions call)`` of compiled HLO text."""
     comps, cur = {}, None
     for line in text.splitlines():
         head = _COMP.match(line)
@@ -224,6 +218,19 @@ def _layer_copies(text, layer_shapes):
             cur[name] = (shape, layout or "", op, rest)
     fused = {c for line in text.splitlines() if " fusion(" in line
              for c in _CALLS.findall(line)}
+    return comps, fused
+
+
+def _layer_copies(text, layer_shapes):
+    """The instructions of compiled HLO that copy one layer's cache within
+    device memory: every dynamic update whose update or result is a
+    layer's cache, and every kernel (an instruction of a computation that
+    no fusion calls) that slices a layer's cache out into HBM.
+
+    A layer's slice staged into on-chip memory (memory space ``S(1)``)
+    for the attention's dot is the one read of it the step must make, and
+    is not counted."""
+    comps, fused = _computations(text)
 
     def ops_in(comp):
         for _, _, op, rest in comps.get(comp, {}).values():
@@ -271,6 +278,32 @@ def test_served_decode_step_writes_caches_in_place(one_chip, model):
     text = compiled.as_text()
     assert _layer_copies(text, layer_shapes) == []
     assert text.count(" while(") == 1
+
+
+def test_served_dsv2_step_reads_experts_in_place(one_chip, monkeypatch):
+    """DeepSeek-V2's decode step as ``RealBackend`` jits it on the chip
+    (the dense layer and 2 MoE layers, 256 slots x 2048, the grouped-matmul
+    kernel), compiled for v5e: no kernel (an instruction outside a fusion's
+    interior) makes a layer's expert weights, so no slice, copy or fusion
+    of them feeds the kernel, which reads them in the stacked weights; the
+    layer scan is still the only loop but the kernel's own search of its
+    group offsets."""
+    cfg = _dsv2_cut(3)
+    # trace the step as a process on the chip does: the kernel, compiled
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, params, _ = _served_step(one_chip, cfg, 256, 2048, "auto")
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    n_held, d, f = cfg.moe.held[1], cfg.d_model, cfg.moe.d_expert
+    stack = params["blocks"]["pos0"]["moe"]["experts"]["w_gate"].shape
+    assert _squeeze(stack) == (2, n_held, d, f)
+    comps, fused = _computations(text)
+    made = [name for cname, insts in comps.items() if cname not in fused
+            for name, (shape, *_) in insts.items()
+            if _dims(shape) in {(n_held, d, f), (n_held, f, d)}]
+    assert made == []
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert len([x for x in loops if "jit(gmm)" not in x]) == 1
 
 
 def test_dsv2_expert_share_kernel_compiles_for_v5e(one_chip):

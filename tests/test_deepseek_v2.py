@@ -168,6 +168,38 @@ def test_grouped_kernel_interpreted_matches_ragged_dot():
     assert int(s0["moe_pairs"]) == int(s1["moe_pairs"]) > 0
 
 
+@pytest.mark.parametrize("interpret", [None, True])
+@pytest.mark.parametrize("layer", [0, 1, 2])
+def test_share_reads_stacked_experts_in_place_like_its_slice(layer, interpret):
+    """A scanned body's stack of three layers' held experts and a layer
+    index give that layer's share, as its slice of the stack does, on both
+    grouped-matmul paths; layer 1's tokens route to no held expert, so it
+    gives the shared expert alone and counts nothing."""
+    cfg = _smoke(held_first=2, n_held=4)
+    layers = [_moe_params(_smoke(), seed=s) for s in range(3)]
+    layers = [dict(p, experts=jax.tree.map(lambda w: w[:, 2:6], p["experts"]))
+              for p in layers]
+    layers[1]["router"] = layers[1]["router"].at[:, 2:6].set(-1e4)
+    stack = jax.tree.map(lambda *w: jnp.stack(w), *[p["experts"]
+                                                    for p in layers])
+    x = jnp.abs(_tokens(cfg, n=40))  # every column of -1e4 scores far below
+    p = layers[layer]
+    y0, s0 = moe.moe_share(p, x, cfg, interpret=interpret)
+    y1, s1 = moe.moe_share(dict(p, experts=stack), x, cfg,
+                           interpret=interpret, layer=jnp.int32(layer))
+    np.testing.assert_array_equal(np.asarray(y1), np.asarray(y0))
+    assert {k: int(v) for k, v in s1.items()} == {k: int(v)
+                                                  for k, v in s0.items()}
+    if layer == 1:
+        shared = moe.mlp_apply(p["shared"], x.reshape(-1, cfg.d_model),
+                               "swiglu")
+        np.testing.assert_allclose(np.asarray(y1).reshape(shared.shape),
+                                   np.asarray(shared), rtol=1e-6, atol=1e-6)
+        assert int(s1["moe_pairs"]) == 0 and int(s1["moe_experts"]) == 0
+    else:
+        assert int(s1["moe_pairs"]) > 0
+
+
 # ---------------------------------------------------------------------------
 # YaRN rotary
 # ---------------------------------------------------------------------------
