@@ -21,6 +21,7 @@ _MODULES = {
     "hubert-xlarge": "hubert_xlarge",
     "mamba2-780m": "mamba2_780m",
     "zamba2-1.2b": "zamba2_1_2b",
+    "nemotron-3-nano-30b": "nemotron_3_nano_30b",
 }
 
 ARCH_IDS = tuple(_MODULES)

@@ -31,14 +31,17 @@ def write_rows(buf: jax.Array, new: jax.Array, index, layer=None) -> jax.Array:
     ``buf`` at its position ``index`` (scalar or [B]): one scatter at
     (slot, index), or at (layer, slot, index) into a layer-stacked
     [G, B, S, ...] buffer, which XLA updates in place.  A position past
-    the end writes the last row, as ``dynamic_update_slice`` clamps."""
+    the end writes the last row, as ``dynamic_update_slice`` clamps; a
+    negative position (a slot not in the step) writes nothing."""
     b, s = new.shape[:2]
     if s != 1:
         raise ValueError(f"one new row per slot, got {s}")
     slots = jnp.arange(b, dtype=jnp.int32)
     pos = jnp.broadcast_to(jnp.asarray(index, jnp.int32), (b,))
+    seq = buf.shape[1 if layer is None else 2]
+    pos = jnp.where(pos < 0, seq, jnp.minimum(pos, seq - 1))
     at = (slots, pos) if layer is None else (layer, slots, pos)
-    return buf.at[at].set(new[:, 0].astype(buf.dtype), mode="clip",
+    return buf.at[at].set(new[:, 0].astype(buf.dtype), mode="drop",
                           unique_indices=True)
 
 
@@ -199,7 +202,17 @@ def gqa_attention(
     Instead we switch to *sequence-parallel attention*: the query sequence
     dim is sharded over the model axis (k/v stay whole), so the quadratic
     score work is partitioned with only O(S·d)-sized gathers.
+
+    ``cfg.partial_rotary`` 0 rotates nothing (nemotron_h's attention).
     """
+    with jax.named_scope("repro.gqa"):
+        return _gqa(p, x, cfg, positions, is_global=is_global, cache=cache,
+                    cache_index=cache_index, return_cache=return_cache,
+                    use_kernel=use_kernel, ctx=ctx, layer=layer)
+
+
+def _gqa(p, x, cfg, positions, *, is_global, cache, cache_index,
+         return_cache, use_kernel, ctx, layer):
     theta = cfg.rope_theta
     window = None
     if not is_global and cfg.sliding_window is not None:

@@ -62,11 +62,25 @@ class MoEConfig:
     # ``n_experts`` outputs and absent experts contribute nothing
     held_first: int = 0
     n_held: int = 0
+    # router scores: "softmax" over the experts, or "sigmoid" per expert
+    # with a learned ``e_score_correction_bias`` added for the choice only
+    # (the gates come from the unbiased scores)
+    scoring: str = "softmax"
+    # renormalise the chosen gates to sum to 1 (before ``router_scale``);
+    # None: only without shared experts (mixtral yes, deepseek-v2 no)
+    norm_topk: Optional[bool] = None
+    # routed and shared experts' FFN: gated "swiglu" (w_gate, w_up, w_down)
+    # or non-gated "relu2" (w_up, w_down)
+    expert_act: str = "swiglu"
 
     @property
     def held(self) -> Tuple[int, int]:
         """``(first, count)`` of the experts this layer holds."""
         return self.held_first, self.n_held or self.n_experts
+
+    @property
+    def renormalise(self) -> bool:
+        return self.n_shared == 0 if self.norm_topk is None else self.norm_topk
 
 
 @dataclass(frozen=True)
@@ -91,8 +105,11 @@ class SSMConfig:
     head_dim: int = 64
     n_groups: int = 1
     chunk: int = 256
+    heads: int = 0                 # SSD heads; 0: expand * d_model / head_dim
 
     def d_inner(self, d_model: int) -> int:
+        if self.heads:
+            return self.heads * self.head_dim
         return self.expand * d_model
 
     def n_heads(self, d_model: int) -> int:
@@ -132,6 +149,9 @@ class ModelConfig:
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     hybrid_attn_every: Optional[int] = None   # zamba2 shared-attn period
+    # one single-mixer block per character (nemotron_h's
+    # ``hybrid_override_pattern``): see :data:`PATTERN_KINDS`
+    layer_pattern: Optional[str] = None
     max_seq_len: int = 131072
     dtype: str = "bfloat16"
 
@@ -153,8 +173,9 @@ class ModelConfig:
         if self.moe is None:
             return total
         m = self.moe
-        n_moe_layers = self.n_layers - m.first_dense_layers
-        per_expert = 3 * self.d_model * m.d_expert
+        n_moe_layers = sum(k.ffn == "moe" for k in layer_plan(self).kinds)
+        per_expert = (3 if m.expert_act == "swiglu" else 2) * self.d_model \
+            * m.d_expert
         inactive = n_moe_layers * (m.n_experts - m.top_k) * per_expert
         return total - inactive
 
@@ -171,8 +192,14 @@ def _param_count_slow(cfg: ModelConfig) -> int:
 
 @dataclass(frozen=True)
 class LayerKind:
-    mixer: str                     # "attn" | "attn_local" | "mamba" | "shared_attn"
-    ffn: str                       # "dense" | "moe" | "none"
+    mixer: str     # "attn" | "attn_local" | "mamba" | "shared_attn" | "none"
+    ffn: str       # "dense" | "moe" | "none"
+
+
+# ``layer_pattern`` characters: each block is one mixer behind one pre-norm
+# residual (nemotron_h): Mamba-2, a routed-expert FFN, attention
+PATTERN_KINDS = {"M": LayerKind("mamba", "none"), "E": LayerKind("none", "moe"),
+                 "*": LayerKind("attn", "none")}
 
 
 @dataclass(frozen=True)
@@ -201,7 +228,12 @@ class LayerPlan:
 
 def layer_plan(cfg: ModelConfig) -> LayerPlan:
     kinds: List[LayerKind] = []
-    for i in range(cfg.n_layers):
+    if cfg.layer_pattern is not None:
+        if len(cfg.layer_pattern) != cfg.n_layers:
+            raise ValueError(f"{cfg.n_layers} layers, pattern "
+                             f"{cfg.layer_pattern!r}")
+        kinds = [PATTERN_KINDS[c] for c in cfg.layer_pattern]
+    for i in range(0 if kinds else cfg.n_layers):
         if cfg.ssm is not None and cfg.hybrid_attn_every:
             # zamba2: shared attention block every `hybrid_attn_every` layers
             if (i + 1) % cfg.hybrid_attn_every == 0:
@@ -227,10 +259,13 @@ def layer_plan(cfg: ModelConfig) -> LayerPlan:
         prefix = cfg.moe.first_dense_layers
     body = kinds[prefix:]
     # smallest period p whose repetition covers a maximal prefix of the body;
-    # the remainder becomes the unrolled suffix
+    # the remainder becomes the unrolled suffix.  A pattern's body is
+    # scanned whole: its period divides it
     period, n_groups = len(body), 1 if body else 0
     for p in range(1, len(body) + 1):
         k = len(body) // p
+        if cfg.layer_pattern is not None and k * p != len(body):
+            continue
         if k >= 1 and all(body[j] == body[j % p] for j in range(k * p)):
             period, n_groups = p, k
             break
@@ -429,14 +464,13 @@ def moe_shapes(cfg: ModelConfig, model_size: int = 1) -> Dict[str, Any]:
     f_c = m.d_expert // tp
     sh: Dict[str, Any] = {
         "router": (d, m.n_experts),
-        "experts": {
-            "w_gate": (nc, n_e, d, f_c),
-            "w_up": (nc, n_e, d, f_c),
-            "w_down": (nc, n_e, f_c, d),
-        },
+        "experts": {name: (nc, n_e) + shape for name, shape in
+                    mlp_shapes(d, f_c, m.expert_act).items()},
     }
+    if m.scoring == "sigmoid":
+        sh["e_score_correction_bias"] = (m.n_experts,)
     if m.n_shared:
-        sh["shared"] = mlp_shapes(d, m.d_shared * m.n_shared, "swiglu")
+        sh["shared"] = mlp_shapes(d, m.d_shared * m.n_shared, m.expert_act)
     return sh
 
 
@@ -555,7 +589,7 @@ def init_params(cfg: ModelConfig, key: jax.Array, dtype=jnp.float32,
             return jnp.log(jnp.expm1(jnp.full(x.shape, 0.01, jnp.float32))).astype(x.dtype)
         if name in ("gate_norm", "q_norm", "k_norm", "kv_norm", "final_norm",
                     "ln_attn", "ln_mlp", "ln_mix", "ln_post_attn", "ln_post_mlp"):
-            return jnp.zeros_like(x) if False else jnp.ones_like(x)
+            return jnp.ones_like(x)
         return x
 
     params = jax.tree_util.tree_map_with_path(fix, params)

@@ -18,8 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .attention import (gqa_attention, init_attn_cache, layer_view,
-                        mla_attention)
+from .attention import gqa_attention, init_attn_cache, mla_attention
 from .common import (LayerKind, LayerPlan, ModelConfig, layer_plan, mlp_apply,
                      param_shapes, rms_norm)
 from .moe import moe_apply, no_stats
@@ -134,21 +133,18 @@ def block_apply(
             new_cache["attn"] = c
     elif kind.mixer == "mamba":
         h = rms_norm(x, p["ln_mix"], eps, gemma=gm)
-        mc = None if cache is None else cache["mamba"]
         y, c = mamba2_block(
             p["mamba"], h, cfg,
-            cache=None if mc is None else jax.tree.map(
-                lambda a: layer_view(a, layer), mc),
+            cache=None if cache is None else cache["mamba"],
+            cache_index=cache_index,
             return_cache=return_cache,
             use_kernel=ctx.use_kernel,
+            layer=layer,
         )
         x = x + y
         if return_cache:
-            if layer is not None:
-                c = jax.tree.map(
-                    lambda a, n: a.at[layer].set(n.astype(a.dtype)), mc, c)
             new_cache["mamba"] = c
-    else:
+    elif kind.mixer != "none":
         raise ValueError(kind.mixer)
 
     stats: Dict[str, jax.Array] = {}
@@ -372,16 +368,15 @@ def loss_fn(
 # ---------------------------------------------------------------------------
 
 def _layer_cache(cfg: ModelConfig, kind: LayerKind, batch: int, max_len: int, dtype):
+    """One layer's cache: every attention layer's K/V at full length (a
+    windowed layer's too), a mamba layer's states, nothing for a block
+    without a mixer."""
     if kind.mixer in ("attn", "attn_local", "shared_attn"):
-        win = cfg.sliding_window
-        ln = max_len
-        if kind.mixer == "attn_local" and win is not None:
-            ln = min(max_len, win)  # ring-capped local cache (allocated full
-            # here for simplicity of positions; engine may cap)
-            ln = max_len
-        return {"attn": init_attn_cache(cfg, batch, ln, dtype)}
+        return {"attn": init_attn_cache(cfg, batch, max_len, dtype)}
     if kind.mixer == "mamba":
         return {"mamba": init_ssm_cache(cfg, batch, dtype)}
+    if kind.mixer == "none":
+        return {}
     raise ValueError(kind.mixer)
 
 
@@ -396,10 +391,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
         for j in range(plan.period):
             one = _layer_cache(cfg, kinds[plan.prefix + j], batch, max_len, dtype)
             body.append(jax.tree.map(
-                lambda a: jnp.broadcast_to(a[None], (plan.n_groups,) + a.shape).copy()
-                if False else jnp.zeros((plan.n_groups,) + a.shape, a.dtype),
-                one,
-            ))
+                lambda a: jnp.zeros((plan.n_groups,) + a.shape, a.dtype), one))
         out["body"] = body
     for i in range(plan.suffix):
         out["suffix"].append(
@@ -449,7 +441,10 @@ def decode_step(
     over the layers; empty for a model without experts).
 
     A scalar ``pos`` steps all sequences in lockstep; a ``[B]`` vector is
-    the continuous-batching path (each session at its own depth).
+    the continuous-batching path (each session at its own depth).  A slot
+    at position 0 starts a session: its recurrent state starts from zero.
+    A slot at a negative position is not in the step: no layer writes its
+    cache or state (its logits are meaningless).
     """
     if tokens.ndim == 1:
         x = jnp.take(params["embed"], tokens[:, None], axis=0).astype(jnp.dtype(cfg.dtype))

@@ -56,8 +56,15 @@ def router_topk(
     router_scale: float,
     n_group: int = 1,
     topk_group: int = 1,
+    scoring: str = "softmax",
+    bias: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Return (gate values [T, K] float32, expert ids [T, K] int32).
+
+    ``scoring`` "softmax" takes the top-k of the softmax and gates by it;
+    "sigmoid" takes the top-k of ``sigmoid(logits) + bias`` (the
+    correction bias steers the choice only) and gates by the chosen
+    experts' ``sigmoid(logits)``.
 
     With ``n_group > 1`` the routing is group-limited (DeepSeek-V2's
     ``group_limited_greedy``): the ``E`` scores form ``n_group`` contiguous
@@ -65,7 +72,13 @@ def router_topk(
     groups are kept (lower index first on ties) and every other score is
     zeroed before the top-k.
     """
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        probs = scores if bias is None else scores + bias.astype(jnp.float32)
+    elif scoring == "softmax":
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    else:
+        raise ValueError(f"unknown router scoring {scoring!r}")
     if n_group > 1:
         t, e = probs.shape
         by_group = probs.reshape(t, n_group, e // n_group)
@@ -74,17 +87,39 @@ def router_topk(
             jnp.arange(t)[:, None], keep].set(True)
         probs = jnp.where(kept[:, :, None], by_group, 0.0).reshape(t, e)
     vals, ids = jax.lax.top_k(probs, top_k)
+    if scoring == "sigmoid":
+        vals = jnp.take_along_axis(scores, ids, axis=-1)
     if norm_topk:
         vals = vals / jnp.maximum(jnp.sum(vals, axis=-1, keepdims=True), 1e-9)
     return vals * router_scale, ids.astype(jnp.int32)
 
 
-def route(x: jax.Array, router: jax.Array, m) -> Tuple[jax.Array, jax.Array]:
-    """The layer's routing of tokens ``x`` [T, d] over all ``n_experts``."""
+def route(x: jax.Array, router: jax.Array, m,
+          bias: Optional[jax.Array] = None) -> Tuple[jax.Array, jax.Array]:
+    """The layer's routing of tokens ``x`` [T, d] over all ``n_experts``;
+    ``bias`` the sigmoid router's ``e_score_correction_bias``."""
     logits = x.astype(jnp.float32) @ router.astype(jnp.float32)
-    return router_topk(logits, m.top_k, norm_topk=(m.n_shared == 0),
+    return router_topk(logits, m.top_k, norm_topk=m.renormalise,
                        router_scale=m.router_scale, n_group=m.n_group,
-                       topk_group=m.topk_group)
+                       topk_group=m.topk_group, scoring=m.scoring, bias=bias)
+
+
+def _route_layer(p: Dict[str, Any], xt: jax.Array, m):
+    """:func:`route` with the layer's router (and its correction bias)."""
+    if "e_score_correction_bias" in p:
+        return route(xt, p["router"], m, p["e_score_correction_bias"])
+    return route(xt, p["router"], m)
+
+
+def _expert_ffn(xt, w, act: str, dot=jnp.matmul):
+    """An expert's FFN on rows ``xt``: gated SwiGLU or non-gated relu²,
+    each weight applied by ``dot(rows, weight)``."""
+    if act == "swiglu":
+        return dot(jax.nn.silu(dot(xt, w["w_gate"])) * dot(xt, w["w_up"]),
+                   w["w_down"])
+    if act == "relu2":
+        return dot(jnp.square(jax.nn.relu(dot(xt, w["w_up"]))), w["w_down"])
+    raise ValueError(f"unknown expert activation {act!r}")
 
 
 def aux_load_balance_loss(logits: jax.Array, ids: jax.Array, n_experts: int) -> jax.Array:
@@ -109,17 +144,17 @@ def moe_ref(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig) -> jax.Array:
     m = cfg.moe
     b, s, d = x.shape
     xt = x.reshape(-1, d)
-    gates, ids = route(xt, p["router"], m)
+    gates, ids = _route_layer(p, xt, m)
     out = jnp.zeros_like(xt, dtype=jnp.float32)
     we = p["experts"]
     first, n_held = m.held
     for e in range(n_held):
         w = jnp.sum(jnp.where(ids == first + e, gates, 0.0), axis=-1)  # [T]
-        h = jax.nn.silu(xt @ we["w_gate"][0, e]) * (xt @ we["w_up"][0, e])
-        out = out + (h @ we["w_down"][0, e]).astype(jnp.float32) * w[:, None]
+        o = _expert_ffn(xt, {k: v[0, e] for k, v in we.items()}, m.expert_act)
+        out = out + o.astype(jnp.float32) * w[:, None]
     y = out.astype(x.dtype)
     if m.n_shared:
-        y = y + mlp_apply(p["shared"], xt, "swiglu")
+        y = y + mlp_apply(p["shared"], xt, m.expert_act)
     return y.reshape(b, s, d)
 
 
@@ -149,13 +184,19 @@ def _grouped(lhs, rhs, sizes, interpret: Optional[bool]):
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     m, k = lhs.shape
+    n = rhs.shape[2]
     tm = min(GMM_TILING[0], -(-m // 8) * 8)
     pad = -m % tm
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
-    tiling = (tm, min(GMM_TILING[1], k), min(GMM_TILING[2], rhs.shape[2]))
-    out = gmm(lhs, rhs, sizes, preferred_element_type=lhs.dtype,
-              tiling=tiling, interpret=interpret)
+    tiling = (tm, min(GMM_TILING[1], k), min(GMM_TILING[2], n))
+    # the chip lays a weight whose last dim is not lane-aligned (128) but
+    # whose contraction dim is with the contraction dim minor: the kernel
+    # reads it through that layout, transposed, or XLA copies it whole
+    flip = n % 128 != 0 and k % 128 == 0
+    out = gmm(lhs, jnp.swapaxes(rhs, 1, 2) if flip else rhs, sizes,
+              preferred_element_type=lhs.dtype, tiling=tiling,
+              transpose_rhs=flip, interpret=interpret)
     return out[:m]
 
 
@@ -182,7 +223,7 @@ def moe_share(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig,
     xt = x.reshape(-1, d)
     first, n_held = m.held
     with jax.named_scope("repro.moe.route"):
-        gates, ids = route(xt, p["router"], m)
+        gates, ids = _route_layer(p, xt, m)
         local = ids.reshape(-1) - first                     # [T*K]
         held = (local >= 0) & (local < n_held)
         key = jnp.where(held, local, n_held)
@@ -196,11 +237,10 @@ def moe_share(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig,
         gs = sizes
         if layer is not None:
             gs = jax.lax.dynamic_update_slice(
-                jnp.zeros(we["w_gate"].shape[:1], jnp.int32), sizes,
+                jnp.zeros(we["w_down"].shape[:1], jnp.int32), sizes,
                 (layer * n_held,))
-        h = (jax.nn.silu(_grouped(xs, we["w_gate"], gs, interpret))
-             * _grouped(xs, we["w_up"], gs, interpret))
-        o = _grouped(h, we["w_down"], gs, interpret)
+        o = _expert_ffn(xs, we, m.expert_act,
+                        lambda a, w: _grouped(a, w, gs, interpret))
         live = jnp.arange(o.shape[0]) < served
         g = jnp.where(held, gates.reshape(-1), 0.0)[order]
         o = jnp.where(live[:, None], o.astype(jnp.float32) * g[:, None], 0.0)
@@ -209,7 +249,7 @@ def moe_share(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig,
         y = o[back].reshape(-1, m.top_k, d).sum(axis=1).astype(x.dtype)
     if m.n_shared:
         with jax.named_scope("repro.moe.shared"):
-            y = y + mlp_apply(p["shared"], xt, "swiglu")
+            y = y + mlp_apply(p["shared"], xt, m.expert_act)
     stats = {"moe_pairs": served,
              "moe_experts": jnp.sum(sizes > 0).astype(jnp.int32)}
     return y.reshape(b, s, d), stats

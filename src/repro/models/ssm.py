@@ -17,6 +17,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .attention import layer_view
 from .common import ModelConfig, rms_norm
 
 
@@ -155,16 +156,51 @@ def _split_in_proj(cfg: ModelConfig, proj: jax.Array):
     return z, xbc, dt                                       # dt: [B,S,nh]
 
 
+def gated_rms_norm(y: jax.Array, z: jax.Array, w: jax.Array, n_groups: int,
+                   eps: float) -> jax.Array:
+    """Mamba2's gated RMSNorm ``norm(y * silu(z)) * w``, the norm taken over
+    each of ``n_groups`` equal groups of the width on its own."""
+    g = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
+    lead, width = g.shape[:-1], g.shape[-1]
+    g = rms_norm(g.reshape(*lead, n_groups, width // n_groups),
+                 w.reshape(n_groups, width // n_groups), eps)
+    return g.reshape(*lead, width)
+
+
 def mamba2_block(
     p: Dict[str, jax.Array],
     x: jax.Array,                 # [B, S, d]
     cfg: ModelConfig,
     *,
     cache: Optional[Dict[str, jax.Array]] = None,
+    cache_index: Optional[jax.Array] = None,
     return_cache: bool = False,
     use_kernel: str = "auto",
+    layer: Optional[jax.Array] = None,
 ) -> Tuple[jax.Array, Optional[Dict[str, jax.Array]]]:
-    """Full Mamba2 mixer.  ``cache`` = {conv [B,K-1,C], ssm [B,H,P,N]}."""
+    """Full Mamba2 mixer.  ``cache`` = {conv [B,K-1,C], ssm [B,H,P,N]}, or
+    with ``layer`` a scanned body's layer-stacked [G, B, ...] states, of
+    which this layer reads and rewrites its entry in place (the returned
+    cache is then the stack).
+
+    A decode step's ``cache_index`` (scalar or [B]) is each slot's position:
+    a slot at 0 starts from zero states, and a slot at a negative position
+    is not in the step and keeps its states exactly."""
+    with jax.named_scope("repro.mamba"):
+        if cache is None or layer is None:
+            return _mamba2(p, x, cfg, cache=cache, cache_index=cache_index,
+                           return_cache=return_cache, use_kernel=use_kernel)
+        y, new = _mamba2(p, x, cfg, cache={k: layer_view(a, layer)
+                                           for k, a in cache.items()},
+                         cache_index=cache_index, return_cache=return_cache,
+                         use_kernel=use_kernel)
+        if new is not None:
+            new = {k: a.at[layer].set(new[k].astype(a.dtype))
+                   for k, a in cache.items()}
+        return y, new
+
+
+def _mamba2(p, x, cfg, *, cache, cache_index, return_cache, use_kernel):
     s = cfg.ssm
     bsz, seq, _ = x.shape
     di = s.d_inner(cfg.d_model)
@@ -178,14 +214,20 @@ def mamba2_block(
 
     if seq == 1 and cache is not None:
         # --- decode: shift conv state, recurrent SSD step --------------------
+        conv0, ssm0 = cache["conv"], cache["ssm"]
+        if cache_index is not None:
+            pos = jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32), (bsz,))
+            fresh = (pos == 0)[:, None, None]
+            conv0 = jnp.where(fresh, jnp.zeros((), conv0.dtype), conv0)
+            ssm0 = jnp.where(fresh[..., None], jnp.zeros((), ssm0.dtype), ssm0)
         conv_state = jnp.concatenate(
-            [cache["conv"], xbc.astype(cache["conv"].dtype)], axis=1)  # [B,K,C]
+            [conv0, xbc.astype(conv0.dtype)], axis=1)              # [B,K,C]
         xbc_t = jnp.einsum("bkc,kc->bc", conv_state.astype(jnp.float32),
                            p["conv_w"].astype(jnp.float32)) + p["conv_b"]
         xbc_t = jax.nn.silu(xbc_t).astype(x.dtype)[:, None, :]
         xs, b_mat, c_mat = jnp.split(xbc_t, [di, di + gn], axis=-1)
         y_t, new_ssm = ssd_recurrent_step(
-            cache["ssm"],
+            ssm0,
             xs.reshape(bsz, nh, s.head_dim),
             dt[:, 0],
             a,
@@ -194,9 +236,16 @@ def mamba2_block(
         )
         y = y_t.reshape(bsz, 1, di)
         y = y + xs * p["D"].astype(x.dtype).repeat(s.head_dim)[None, None, :]
-        new_cache = (
-            {"conv": conv_state[:, 1:, :], "ssm": new_ssm} if return_cache else None
-        )
+        new_cache = None
+        if return_cache:
+            new_cache = {"conv": conv_state[:, 1:, :], "ssm": new_ssm}
+            if cache_index is not None:
+                idle = pos < 0
+                new_cache = jax.tree.map(
+                    lambda old, new: jnp.where(
+                        idle.reshape((bsz,) + (1,) * (new.ndim - 1)),
+                        old.astype(new.dtype), new),
+                    dict(cache), new_cache)
     else:
         # --- train / prefill: chunked scan -----------------------------------
         conv_in_state = cache["conv"] if cache is not None else None
@@ -239,9 +288,7 @@ def mamba2_block(
                 tail = jnp.pad(xbc, ((0, 0), (k - 1 - seq, 0), (0, 0)))
             new_cache = {"conv": tail, "ssm": final}
 
-    # gated RMSNorm (Mamba2: norm(y * silu(z)))
-    y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype),
-                 p["gate_norm"], cfg.norm_eps)
+    y = gated_rms_norm(y, z, p["gate_norm"], s.n_groups, cfg.norm_eps)
     return y @ p["w_out"], new_cache
 
 

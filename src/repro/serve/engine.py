@@ -187,7 +187,8 @@ class RealBackend:
         dev = self.devices[pod]
         with host_span("repro.serve.inputs"):
             tokens = np.zeros((st.n_slots,), np.int32)
-            pos = np.zeros((st.n_slots,), np.int32)
+            # a slot not in the step: a negative position, written nowhere
+            pos = np.full((st.n_slots,), -1, np.int32)
             for sid in sids:
                 s = st.sessions[sid]
                 tokens[s.slot] = s.last_token

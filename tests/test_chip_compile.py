@@ -156,6 +156,36 @@ def test_glm4_cut_decode_step_fits_one_chip(one_chip):
     assert total < HBM_BYTES, total
 
 
+def _nemotron_cut():
+    """NVIDIA-Nemotron-3-Nano at published widths, cut to pipeline stage
+    0's seven blocks ``MEMEM*E`` (3 Mamba-2, 3 MoE of 128 experts, 1 GQA),
+    as the benchmark's cell runs it."""
+    from repro.configs import get_config
+
+    return dataclasses.replace(get_config("nemotron-3-nano-30b"), n_layers=7,
+                               layer_pattern="MEMEM*E")
+
+
+def test_nemotron_cut_decode_step_fits_one_chip(one_chip):
+    """The nemotron cut's decode step as ``RealBackend`` jits it on the chip
+    (published widths, 384 slots x 2048 positions, bf16 weights, the SSM
+    state in float32, the grouped-matmul kernel) compiles for v5e, and its
+    weights, donated caches and temporaries fit the chip: 12,809,554,304
+    bytes (74.6% of 16 GiB) when written."""
+    import unittest.mock as mock
+
+    cfg = _nemotron_cut()
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        compiled, params, caches = _served_step(one_chip, cfg, 384, 2048,
+                                                "auto")
+    ma = compiled.memory_analysis()
+    p_bytes, c_bytes = _nbytes(params), _nbytes(caches)
+    assert ma.argument_size_in_bytes >= p_bytes + c_bytes
+    assert ma.alias_size_in_bytes >= c_bytes
+    total = p_bytes + c_bytes + ma.temp_size_in_bytes
+    assert total < 0.8 * HBM_BYTES, total
+
+
 def _dsv2_cut(n_layers):
     """DeepSeek-V2 at published widths holding routing group 0 (experts
     0-19), as the benchmark's cell runs it, ``n_layers`` deep."""
@@ -254,21 +284,38 @@ def _layer_copies(text, layer_shapes):
     return found
 
 
-@pytest.mark.parametrize("model", ["glm4-9b", "deepseek-v2"])
+def _whole_copies(text, layer_shapes):
+    """The ``copy`` kernels (outside a fusion's interior) that make a
+    layer's whole cache or state in device memory (not staged on chip)."""
+    comps, fused = _computations(text)
+    return [name for cname, insts in comps.items() if cname not in fused
+            for name, (shape, layout, op, _) in insts.items()
+            if op == "copy" and _dims(shape) in layer_shapes
+            and "S(1)" not in layout]
+
+
+@pytest.mark.parametrize("model", ["glm4-9b", "deepseek-v2",
+                                   "nemotron-3-nano"])
 def test_served_decode_step_writes_caches_in_place(one_chip, model):
     """The decode step as ``RealBackend`` jits it, at the cells' sizes
     (glm4-9b: 2 scanned layers, 32 slots x 4096; DeepSeek-V2: the dense
-    layer and 2 MoE layers, 256 slots x 2048), compiled for v5e: every
-    cache buffer is donated and aliased to its output, no kernel slices a
-    layer's cache out of the stack or writes one back, and the per-slot
-    row writes lower to no loop (the layer scan is the only one)."""
+    layer and 2 MoE layers, 256 slots x 2048; nemotron: the seven blocks
+    ``MEMEM*E``, 384 slots x 2048, its SSM and conv states beside the GQA
+    block's K/V), compiled for v5e: every cache buffer is donated and
+    aliased to its output, no kernel slices a layer's cache or state out
+    of the stack or writes one back (nor, for nemotron, copies one whole),
+    and the per-slot row writes lower to no loop (the layer scan is the
+    only one, and a scan of one group is none)."""
     from repro.configs import get_config
+    from repro.models.common import layer_plan
 
     if model == "glm4-9b":
         cfg = dataclasses.replace(get_config("glm4-9b"), n_layers=2)
         slots, max_len = 32, 4096
-    else:
+    elif model == "deepseek-v2":
         cfg, slots, max_len = _dsv2_cut(3), 256, 2048
+    else:
+        cfg, slots, max_len = _nemotron_cut(), 384, 2048
     compiled, _, caches = _served_step(one_chip, cfg, slots, max_len)
     assert compiled.memory_analysis().alias_size_in_bytes >= _nbytes(caches)
     # a body leaf is [layers, slots, positions, ...]; the others one layer's
@@ -277,7 +324,23 @@ def test_served_decode_step_writes_caches_in_place(one_chip, model):
                      for x in jax.tree.leaves(caches[k])}
     text = compiled.as_text()
     assert _layer_copies(text, layer_shapes) == []
-    assert text.count(" while(") == 1
+    if model == "nemotron-3-nano":
+        # DeepSeek-V2's unrolled first layer relays its rotary keys out
+        # whole (two copies of [256, 2048, 64]), as it did before
+        assert _whole_copies(text, layer_shapes) == []
+    assert text.count(" while(") == int(layer_plan(cfg).n_groups > 1)
+
+
+def _experts_made(text, cfg):
+    """The kernels (instructions outside a fusion's interior, the weights
+    themselves and their free reshapes aside) that make a layer's expert
+    weights."""
+    n_held, d, f = cfg.moe.held[1], cfg.d_model, cfg.moe.d_expert
+    comps, fused = _computations(text)
+    return [name for cname, insts in comps.items() if cname not in fused
+            for name, (shape, _, op, _) in insts.items()
+            if _dims(shape) in {(n_held, d, f), (n_held, f, d)}
+            and op not in ("parameter", "bitcast")]
 
 
 def test_served_dsv2_step_reads_experts_in_place(one_chip, monkeypatch):
@@ -304,6 +367,24 @@ def test_served_dsv2_step_reads_experts_in_place(one_chip, monkeypatch):
     assert made == []
     loops = [line for line in text.splitlines() if " while(" in line]
     assert len([x for x in loops if "jit(gmm)" not in x]) == 1
+
+
+def test_served_nemotron_step_reads_experts_in_place(one_chip, monkeypatch):
+    """The nemotron cut's decode step on the chip (384 slots x 2048, the
+    grouped-matmul kernel), compiled for v5e: the three MoE blocks' relu²
+    experts (128 of them, ``w_up`` [2688, 1856], which the chip lays out
+    with 2688 minor, and ``w_down``) reach the kernel with no slice, copy
+    or relayout of them; the only loops are the kernel's own."""
+    cfg = _nemotron_cut()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, params, _ = _served_step(one_chip, cfg, 384, 2048, "auto")
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 6
+    assert set(params["blocks"]["pos1"]["moe"]["experts"]) == {"w_up",
+                                                               "w_down"}
+    assert _experts_made(text, cfg) == []
+    loops = [line for line in text.splitlines() if " while(" in line]
+    assert all("jit(gmm)" in x for x in loops)
 
 
 def test_dsv2_expert_share_kernel_compiles_for_v5e(one_chip):

@@ -124,6 +124,7 @@ def test_full_config_shapes_consistent(arch):
         "gemma3-27b": (2.2e10, 3.0e10), "deepseek-v2-236b": (2.1e11, 2.5e11),
         "mixtral-8x7b": (4.2e10, 5.0e10), "hubert-xlarge": (0.8e9, 1.3e9),
         "mamba2-780m": (6.5e8, 9.5e8), "zamba2-1.2b": (1.0e9, 1.6e9),
+        "nemotron-3-nano-30b": (3.0e10, 3.3e10),
     }[arch]
     assert expected[0] < cfg.param_count() < expected[1], cfg.param_count()
 

@@ -525,8 +525,8 @@ def test_donated_decode_step_matches_per_layer_writes(arch):
     GQA (glm4), MLA with a dense unrolled first layer (DeepSeek-V2), and
     mamba layers in the scanned body and the unrolled suffix beside a
     shared attention block (Zamba2).  Four steps with slots at different
-    depths and one slot idle (token 0 at position 0, as ``RealBackend``
-    passes it), then one step of the scalar-position path."""
+    depths and one slot restarted every step (token 0 at position 0),
+    then one step of the scalar-position path."""
     cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32")
     params = init_params(cfg, jax.random.PRNGKey(3))
     be = RealBackend(cfg, CTX, params, n_pods=1, n_slots=4, max_len=16)
@@ -555,3 +555,73 @@ def test_donated_decode_step_matches_per_layer_writes(arch):
         jax.tree.map(lambda a, b: np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-5,
             err_msg=str(t)), st.caches, want_caches)
+
+
+# ---------------------------------------------------------------------------
+# Slots that sit out steps
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("arch", ["glm4-9b", "nemotron-3-nano-30b"])
+def test_slots_outside_a_step_keep_their_sessions(arch):
+    """``RealBackend`` steps three sessions in changing subsets (the others'
+    slots sit those steps out), a fourth takes over a freed slot: every
+    step's logits for each session match its reference forward over the
+    tokens it was served (glm4: the uncached forward; nemotron: the float32
+    sequential reference), so no idle step wrote a slot's K/V row or
+    advanced its recurrent state, and a reused slot started afresh."""
+    if arch == "glm4-9b":
+        cfg = CFG
+        params = init_params(cfg, jax.random.PRNGKey(4))
+
+        def reference(seq):
+            return np.asarray(decoder.forward(
+                cfg, CTX, params, {"tokens": jnp.asarray(seq)[None]})[0])
+    else:
+        from test_nemotron_h import _params, _ref_logits
+
+        cfg = dataclasses.replace(get_smoke_config(arch), dtype="float32")
+        params = _params(cfg, seed=4)
+
+        def reference(seq):
+            return _ref_logits(params, cfg, np.asarray(seq))
+
+    be = RealBackend(cfg, CTX, params, n_pods=1, n_slots=4, max_len=16)
+    st = be.stores[0]
+    st.caches = decoder.init_cache(cfg, 4, 16, jnp.float32)
+    logged, step = [], be._step
+
+    def spy(p, caches, tokens, pos):
+        out = step(p, caches, tokens, pos)
+        logged.append((np.asarray(pos), np.asarray(out[0])))
+        return out
+
+    be._step = spy
+    seqs, rows = {}, {}
+
+    def start(sid, prompt):
+        be.ensure(0, sid, 0)
+        st.sessions[sid].last_token = prompt
+        seqs[sid], rows[sid] = [prompt], []
+
+    for sid, prompt in ((0, 11), (1, 22), (2, 33)):
+        start(sid, prompt)
+    plan = [[0, 1, 2], [0], [0, 2], [1], [2], [0, 1, 2], "swap", [3, 2],
+            [3], [1, 3]]
+    for sids in plan:
+        if sids == "swap":           # session 0 leaves, 3 takes its slot
+            slot = st.sessions[0].slot
+            be.drop(0, 0)
+            start(3, 44)
+            assert st.sessions[3].slot == slot
+            continue
+        slots = {sid: st.sessions[sid].slot for sid in sids}
+        out = be.step(0, sids)
+        pos, logits = logged[-1]
+        assert sorted(np.flatnonzero(pos >= 0)) == sorted(slots.values())
+        for sid in sids:
+            rows[sid].append(logits[slots[sid]])
+            seqs[sid].append(out[sid])
+    for sid, got in rows.items():
+        want = reference(seqs[sid][:-1])
+        np.testing.assert_allclose(np.stack(got), want, rtol=2e-3, atol=2e-3,
+                                   err_msg=str(sid))
